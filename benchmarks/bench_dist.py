@@ -39,15 +39,15 @@ def _child() -> None:
     from repro import configs
     from repro.core import dist_plan as dp
     from repro.core import stencil as st
-    from repro.launch.mesh import make_mesh_compat
     from repro.models import moe
 
     rng = np.random.default_rng(0)
-    mesh = make_mesh_compat((8,), ("x",))
+    auto = jax.sharding.AxisType.Auto
+    mesh = jax.make_mesh((8,), ("x",), axis_types=(auto,))
     mk = dp.mesh_key(mesh)
     # a second, 2-axis mesh: requesting the output on the OTHER axis has no
     # aligned collective, which is what exercises the replicate fallback
-    mesh2 = make_mesh_compat((2, 4), ("a", "b"))
+    mesh2 = jax.make_mesh((2, 4), ("a", "b"), axis_types=(auto,) * 2)
 
     # --- sharded permute: one op, three strategies -----------------------
     shape, dt = ((16, 16, 32) if common.smoke() else (64, 128, 256)), jnp.float32
@@ -165,9 +165,12 @@ def run() -> list[str]:
     from benchmarks import common
     from repro.launch.mesh import fake_device_env
 
+    # the forced host devices are CPU devices: pin the child to the CPU so
+    # it never contends for an accelerator this process may hold
     env = {
         **os.environ,
         **fake_device_env(8),
+        "JAX_PLATFORMS": "cpu",
         "REPRO_DIST_BENCH_CHILD": "1",
         "PYTHONPATH": "src" + os.pathsep + os.environ.get("PYTHONPATH", ""),
     }

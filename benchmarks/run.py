@@ -38,6 +38,7 @@ import sys
 import time
 
 from benchmarks import common
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = [
     ("copy", "benchmarks.bench_copy", "Fig. 1 read/write kernels"),
@@ -109,7 +110,9 @@ def main() -> None:
             # smoke runs never overwrite the committed bare-metal numbers
             setattr(args, attr, "" if args.smoke else path)
 
+    enable_compile_cache()
     common.RECORDS.clear()
+    failed: list[str] = []
     print("name,us_per_call,derived")
     for key, module, title in SUITES:
         if only and key not in only:
@@ -124,6 +127,7 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001 — keep the harness running
             print(f"# {key} FAILED: {type(e).__name__}: {e}", file=sys.stderr)
             print(f"{key},error,{type(e).__name__}")
+            failed.append(key)
         for rec in common.RECORDS[n_before:]:
             rec.setdefault("suite", key)
         print(f"# ({time.time()-t0:.1f}s)", flush=True)
@@ -156,6 +160,11 @@ def main() -> None:
                 )
                 f.write("\n")
             print(f"# wrote {path} ({len(suite_rows)} rows)", flush=True)
+
+    if failed:
+        # the error rows above are kept; the run itself must not pass
+        print(f"# FAILED suites: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -26,18 +26,17 @@ os.environ["XLA_FLAGS"] = (
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro import configs  # noqa: E402
 from repro.core import dist_plan as dp  # noqa: E402
 from repro.core import stencil as st  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
 from repro.models import moe  # noqa: E402
 
 
 def main() -> None:
     rng = np.random.default_rng(0)
-    mesh = make_mesh_compat((8,), ("x",))
+    mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
     print(f"devices: {jax.device_count()}  mesh: {dict(dp.mesh_key(mesh))}")
 
     # 1 — sharded permute: (B, S, D) sharded over B, swap B and S

@@ -229,6 +229,10 @@ def _build_rearrange(
     derived = permuted_spec(in_spec, perm)
     if out_spec is None:
         out_spec = derived
+    if n_elems == 0 and strategy is None:
+        # nothing to move or exchange: the output is an empty array
+        return _mk("rearrange", "noop", mesh_shape, None, in_spec, out_spec,
+                   (shape, dtype_name, perm), (), (), 0, 0)
 
     def shard_div(spec_t):
         """Local shape under spec_t; None when some sharded dim is ragged.
@@ -802,14 +806,16 @@ def shard_permute(
         z = shard_permute(x, (1, 0, 2), mesh=mesh, in_spec=P("b"),
                           out_spec=P(None, None, "b"))   # one all_to_all
     """
-    from repro.launch.mesh import shard_map_compat
-
     perm = tuple(int(p) for p in perm)
     plan = plan_dist_rearrange(
         mesh_key(mesh), spec_key(in_spec, x.ndim),
         None if out_spec is None else spec_key(out_spec, x.ndim),
         x.shape, x.dtype, perm, tuned=tuned,
     )
+    if plan.strategy == "noop":
+        # an empty array has no shards to move (and XLA reports empty
+        # outputs as replicated, whatever sharding is requested)
+        return jnp.zeros(tuple(x.shape[p] for p in perm), x.dtype)
     if plan.strategy == "local":
         f = lambda xl: ops.permute(xl, perm)  # noqa: E731
     elif plan.strategy == "all_to_all":
@@ -833,8 +839,9 @@ def shard_permute(
                 y = jax.lax.dynamic_slice_in_dim(y, start, n_loc, axis=j)
             return y
 
-    return shard_map_compat(
-        f, mesh, in_specs=(_pspec(plan.in_spec),), out_specs=_pspec(plan.out_spec)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=(_pspec(plan.in_spec),),
+        out_specs=_pspec(plan.out_spec), check_vma=False,
     )(x)
 
 
@@ -843,8 +850,6 @@ def shard_interlace(arrays: Sequence[Array], *, mesh, spec) -> Array:
     axis.  Always comm-free (see :func:`plan_dist_interlace`); each shard
     runs the existing single-kernel interlace and the output keeps ``spec``.
     """
-    from repro.launch.mesh import shard_map_compat
-
     arrays = list(arrays)
     if not arrays:
         raise ValueError("interlace wants at least one array")
@@ -853,10 +858,10 @@ def shard_interlace(arrays: Sequence[Array], *, mesh, spec) -> Array:
         arrays[0].dtype, len(arrays),
     )
     f = lambda *ls: ops.interlace(list(ls))  # noqa: E731
-    return shard_map_compat(
-        f, mesh,
+    return jax.shard_map(
+        f, mesh=mesh,
         in_specs=tuple(_pspec(plan.in_spec) for _ in arrays),
-        out_specs=_pspec(plan.out_spec),
+        out_specs=_pspec(plan.out_spec), check_vma=False,
     )(*arrays)
 
 
@@ -879,7 +884,7 @@ def shard_stencil(
     kept.  Bit-identical to ``program(x, boundary=...)`` on one device.
     """
     from repro.core import stencil as st
-    from repro.launch.mesh import ring_perm, shard_map_compat
+    from repro.launch.mesh import ring_perm
 
     if x.ndim != 2:
         raise ValueError(f"stencil programs want 2-D grids, got {x.shape}")
@@ -926,8 +931,9 @@ def shard_stencil(
                 xl = jax.lax.slice_in_dim(y, r_b, r_b + hl, axis=0) if r_b else y
             return xl
 
-    return shard_map_compat(
-        f, mesh, in_specs=(_pspec(plan.in_spec),), out_specs=_pspec(plan.out_spec)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=(_pspec(plan.in_spec),),
+        out_specs=_pspec(plan.out_spec), check_vma=False,
     )(x)
 
 

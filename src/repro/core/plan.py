@@ -47,6 +47,7 @@ from repro.kernels.tiling import (
     transpose_tile_candidates,
     vec_tile_candidates,
 )
+from repro.kernels.reorder_nd import affine_tiling_ok
 from repro.utils.roofline import movement_cost_s
 
 # v5e per-chip hardware constants (also used by utils.roofline)
@@ -61,7 +62,7 @@ class RearrangePlan:
     (collapsed) form, the kernel route, the chosen tiles, and the predicted
     HBM traffic/roofline (DESIGN.md §3)."""
 
-    mode: str  # identity | copy | transpose | reorder | affine
+    mode: str  # identity | copy | transpose | reorder | affine | oracle
     kernel: str  # noop | copy | transpose2d_batched[_vec] | reorder_nd | reorder_affine
     canonical_shape: tuple[int, ...]
     canonical_perm: tuple[int, ...]
@@ -75,15 +76,19 @@ class RearrangePlan:
     block_v: int | None = None  # lane-depth tile on the _vec route
     plan_source: str = "heuristic"  # heuristic | analytic | tuned
     amap: affine.AffineMap | None = None  # merged map, affine-mode plans
+    # False when the kernel breaks the TPU tiling rule (affine mode only):
+    # dispatch then runs the oracle on the chip (the interpreter has no rule)
+    tpu_kernel: bool = True
 
     def describe(self) -> str:
         """One-line human-readable summary (benchmarks / debugging)."""
         tiles = f"tiles=({self.block_r},{self.block_c}"
         tiles += f",{self.block_v})" if self.block_v is not None else ")"
         ex = f" exec={self.exec_shape}" if self.exec_shape is not None else ""
+        tpu = "kernel" if self.tpu_kernel else "oracle"
         return (
             f"{self.mode}: shape={self.canonical_shape} perm={self.canonical_perm} "
-            f"kernel={self.kernel} {tiles}{ex} source={self.plan_source} "
+            f"kernel={self.kernel} {tiles}{ex} source={self.plan_source} tpu={tpu} "
             f"{self.bytes_moved/1e6:.2f} MB moved, "
             f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_GBPS} GB/s"
         )
@@ -395,9 +400,21 @@ def _plan_affine_cached(
             grid_order=grid_order, bytes_moved=0, roofline_s=0.0,
             plan_source="analytic",
         )
-    ex = affine.derive(amap, dtype_name, grid_order)
-    m = ex.amap
     bytes_moved = 2 * n_out * itemsize
+    try:
+        ex = affine.derive(amap, dtype_name, grid_order)
+    except ValueError:
+        # no single-pass lowering (e.g. a rotated lane digit that cannot be
+        # resident): the explicit oracle route, on every platform
+        return RearrangePlan(
+            mode="oracle", kernel="ref",
+            canonical_shape=amap.in_digits, canonical_perm=amap.src,
+            out_shape=out_shape, exec_shape=None, block_r=1, block_c=1,
+            grid_order=grid_order, bytes_moved=bytes_moved,
+            roofline_s=bytes_moved / (HBM_GBPS * 1e9), plan_source="analytic",
+            tpu_kernel=False,
+        )
+    m = ex.amap
     if ex.mode == "transpose":
         kernel = (
             "transpose2d_batched_vec" if ex.block_v is not None
@@ -416,6 +433,7 @@ def _plan_affine_cached(
         bytes_moved=bytes_moved, roofline_s=bytes_moved / (HBM_GBPS * 1e9),
         block_v=ex.block_v, plan_source="analytic",
         amap=m if ex.mode == "affine" else None,
+        tpu_kernel=affine_tiling_ok(ex),
     )
 
 
@@ -482,8 +500,8 @@ def _plan_affine_tuned_cached(
     amap: affine.AffineMap, dtype_name: str, grid_order: str, mode: str
 ) -> RearrangePlan:
     base = _plan_affine_cached(amap, dtype_name, grid_order)
-    if base.mode == "identity":
-        return base  # nothing to tune: no data moves
+    if base.mode in ("identity", "oracle"):
+        return base  # nothing to tune: no data moves, or no kernel
     cands = _affine_tile_candidates(base, dtype_name)
     key = (
         f"amap={amap.in_digits}->{amap.out_digits}|src={amap.src}|"
@@ -520,8 +538,10 @@ def plan_affine(
     (``affine.merge_runs``), classified, and tiled in closed form by
     :func:`affine.derive` — permutation-class maps land on the existing
     kernel routes, anything with window bases / rotations / skew lands on
-    the generalized ``reorder_affine`` kernel.  Raises ValueError when the
-    map has no single-pass lowering (callers fall back to their oracle).
+    the generalized ``reorder_affine`` kernel.  A map with no single-pass
+    lowering plans as ``mode="oracle"``; a kernel that breaks the TPU
+    tiling rule plans with ``tpu_kernel=False`` — both shown by
+    ``describe()`` and obeyed by the dispatch layer.
     ``tuned`` resolves like :func:`plan_rearrange`; because the seed is the
     derivation itself, tuning is a verification pass over its ±1
     neighborhood.

@@ -173,29 +173,16 @@ def _build_plan(
     R = sum(radii)
     n = H * W
 
-    def col_ok(r: int) -> bool:
-        if r == 0:
-            return True
-        if boundary == "reflect":
-            return W >= r + 1
-        if boundary == "periodic":
-            return W >= r
-        return True
-
     br = rp = 0
     mode = "reference"
-    if n > 0 and all(col_ok(r) for r in radii):
-        try:
-            br, rp, _ = st_k.pick_panel(
-                H, W, dtype_name, R, boundary, block_rows=block_rows
-            )
+    if n > 0:
+        panel = st_k.fused_panel(H, W, dtype_name, radii, boundary,
+                                 block_rows=block_rows)
+        if panel is not None:
+            br, rp, _ = panel
             mode = "fused"
-        except ValueError:
-            if block_rows is not None:
-                raise  # the tuner asked for an illegal panel: skip candidate
-            br = rp = 0
-    elif block_rows is not None:
-        raise ValueError("no fused path to tune for this shape/boundary")
+    if block_rows is not None and mode != "fused":
+        raise ValueError("no fused panel for this block_rows override")
     grid = cdiv(H, br) if br else 0
 
     # cost model: useful traffic is one read + one write of the grid; the

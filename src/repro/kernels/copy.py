@@ -33,18 +33,26 @@ def _copy_range_kernel(s_ref, x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
+_LANE_COLS = (8192, 4096, 2048, 1024, 512, 256, LANES)
+
+
+def has_view(shape: tuple[int, ...]) -> bool:
+    """True when :func:`copy` has a (rows, cols) kernel view of ``shape``:
+    any rank >= 2 array, or a 1-D length with a lane-aligned factor.
+    Dispatch routes other shapes to the oracle before building a kernel."""
+    if len(shape) >= 2:
+        return True
+    return len(shape) == 1 and any(shape[0] % c == 0 for c in _LANE_COLS)
+
+
 def _as_2d(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
-    """View x as (rows, cols) with a lane-friendly cols if possible."""
+    """View x as (rows, cols) with a lane-friendly cols (see has_view)."""
     if x.ndim >= 2:
         return x.reshape(-1, x.shape[-1]), x.shape
+    if not has_view(x.shape):
+        raise ValueError(f"1-D length {x.shape} has no lane-aligned factor")
     L = x.shape[0]
-    cols = 1
-    for cand in (8192, 4096, 2048, 1024, 512, 256, LANES):
-        if L % cand == 0:
-            cols = cand
-            break
-    if cols == 1:
-        raise ValueError(f"1-D length {L} has no lane-aligned factor")
+    cols = next(c for c in _LANE_COLS if L % c == 0)
     return x.reshape(L // cols, cols), x.shape
 
 

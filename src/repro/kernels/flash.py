@@ -82,7 +82,7 @@ def _flash_kernel(
         o_ref[0] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
+        lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
 def _flash_call(
@@ -118,11 +118,11 @@ def _flash_call(
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b * hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -302,7 +302,7 @@ def _flash_tri_call(
             o_ref[0] = (
                 acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
             ).astype(o_ref.dtype)
-            lse_ref[0] = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
+            lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -314,7 +314,7 @@ def _flash_tri_call(
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, t, tab: (bh, tab[0, t], 0)),
-            pl.BlockSpec((1, bq), lambda bh, t, tab: (bh, tab[0, t])),
+            pl.BlockSpec((1, bq, 1), lambda bh, t, tab: (bh, tab[0, t], 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -327,7 +327,7 @@ def _flash_tri_call(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b * hq, sq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(tables, q3, k3, v3)
@@ -446,13 +446,13 @@ def _flash_bwd_dq_kernel(
         valid = (q_idx < sq) & (k_pos < skv)
         if causal:
             valid = valid & (q_offset + q_idx >= k_pos)
-        lse = lse_ref[0]  # (bq,) fp32
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
+        lse = lse_ref[0]  # (bq, 1) fp32
+        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
-        delta = jnp.where(q_rows[:, 0] < sq, delta_ref[0], 0.0)  # (bq,)
-        ds = p * (dp - delta[:, None])
+        delta = jnp.where(q_rows < sq, delta_ref[0], 0.0)  # (bq, 1)
+        ds = p * (dp - delta)
         acc_ref[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -495,12 +495,12 @@ def _flash_bwd_dkv_kernel(
         if causal:
             valid = valid & (q_offset + q_idx >= k_pos)
         lse = lse_ref[0]
-        p = jnp.where(valid, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        delta = jnp.where(q_rows[:, 0] < sq, delta_ref[0], 0.0)
-        ds = p * (dp - delta[:, None])
+        delta = jnp.where(q_rows < sq, delta_ref[0], 0.0)
+        ds = p * (dp - delta)
         # contract over the q rows (axis 0 of both operands) -> (bk, d)
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -563,11 +563,11 @@ def flash_attention_bwd(
     k3 = k.reshape(b * hkv, skv, d)
     v3 = v.reshape(b * hkv, skv, d)
     do3 = do.reshape(b * hq, sq, d)
-    lse2 = lse.reshape(b * hq, sq)
+    lse2 = lse.reshape(b * hq, sq, 1)
     # delta = rowsum(do * o): O(S.D) elementwise in fp32, never s x s
     delta2 = (
         (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
-    ).reshape(b * hq, sq)
+    ).reshape(b * hq, sq, 1)
 
     dq3 = pl.pallas_call(
         functools.partial(
@@ -579,8 +579,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, bk, d), lambda bh, iq, ik: (bh // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, iq, ik: (bh // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
@@ -598,8 +598,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh // g, ik, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh // g, ik, 0)),
             pl.BlockSpec((1, bq, d), lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, ik, iq: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, ik, iq: (bh, iq)),
+            pl.BlockSpec((1, bq, 1), lambda bh, ik, iq: (bh, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, ik, iq: (bh, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh, ik, 0)),
@@ -929,35 +929,35 @@ def _decode_split_kernel(
     @pl.when(ik == nks - 1)
     def finalize():
         o_ref[0, 0] = acc_ref[...]
-        m_out_ref[0, 0] = m_ref[:, 0]
-        l_out_ref[0, 0] = l_ref[:, 0]
+        m_out_ref[0, 0] = m_ref[...]
+        l_out_ref[0, 0] = l_ref[...]
 
 
 def _decode_combine_kernel(ns: int, mid_o_ref, mid_m_ref, mid_l_ref, o_ref):
     """Stage 2: fold the per-split (m, l, acc) partials with a running
     mid-softmax rescale — the `_fwd_kernel_stage2_asm` recurrence."""
     g, d = o_ref.shape[1], o_ref.shape[2]
-    e_max = jnp.full((g,), NEG_INF, jnp.float32)
-    e_sum = jnp.zeros((g,), jnp.float32)
+    e_max = jnp.full((g, 1), NEG_INF, jnp.float32)
+    e_sum = jnp.zeros((g, 1), jnp.float32)
     acc = jnp.zeros((g, d), jnp.float32)
     for i in range(ns):
         tv = mid_o_ref[0, i]  # (G, d) unnormalized partial
-        tm = mid_m_ref[0, i]  # (G,) split max
-        tl = mid_l_ref[0, i]  # (G,) split exp-sum
+        tm = mid_m_ref[0, i]  # (G, 1) split max
+        tl = mid_l_ref[0, i]  # (G, 1) split exp-sum
         n_e_max = jnp.maximum(tm, e_max)
         old_scale = jnp.exp(e_max - n_e_max)
         p = jnp.exp(tm - n_e_max)
-        acc = acc * old_scale[:, None] + p[:, None] * tv
+        acc = acc * old_scale + p * tv
         e_sum = e_sum * old_scale + p * tl
         e_max = n_e_max
-    o_ref[0] = (acc / jnp.maximum(e_sum, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(e_sum, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_splits", "interpret"))
 def decode_combine(
     mid_o: jax.Array,  # (BH, ns, G, d) float32
-    mid_m: jax.Array,  # (BH, ns, G) float32
-    mid_l: jax.Array,  # (BH, ns, G) float32
+    mid_m: jax.Array,  # (BH, ns, G, 1) float32
+    mid_l: jax.Array,  # (BH, ns, G, 1) float32
     *,
     num_splits: int,
     interpret: bool | None = None,
@@ -972,8 +972,8 @@ def decode_combine(
         grid=(bh,),
         in_specs=[
             pl.BlockSpec((1, ns, g, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, ns, g), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, ns, g), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, ns, g, 1), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, ns, g, 1), lambda i: (i, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, g, d), mid_o.dtype),
@@ -1047,8 +1047,8 @@ def flash_decode(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bh, isp, ik, lens: (bh, isp, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda bh, isp, ik, lens: (bh, isp, 0)),
-            pl.BlockSpec((1, 1, g), lambda bh, isp, ik, lens: (bh, isp, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda bh, isp, ik, lens: (bh, isp, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda bh, isp, ik, lens: (bh, isp, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
@@ -1061,8 +1061,8 @@ def flash_decode(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b * hkv, ns, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, ns, g), jnp.float32),
-            jax.ShapeDtypeStruct((b * hkv, ns, g), jnp.float32),
+            jax.ShapeDtypeStruct((b * hkv, ns, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * hkv, ns, g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(lens, q3, k3, v3)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import cdiv, force_interpret, plan_copy_tiles
+from repro.kernels.tiling import cdiv, force_interpret, plan_copy_tiles, sublanes
 
 # ---------------------------------------------------------------------------
 # row-wise kernels (seed generation; benchmark baseline)
@@ -150,56 +150,95 @@ def _row_dma(src_hbm, s, rows_vmem, r, sem):
     cp.wait()
 
 
-def _gather_block_kernel(use_run: bool, idx_ref, x_hbm, o_ref, rows, sem):
+def _wide(dtype):
+    """The 32-bit type rows are rotated in (Mosaic rotates only 32-bit
+    data; narrower types widen exactly and narrow back)."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
+def _fetch_rows(x_hbm, tiles, sem, srcs):
+    """Rows ``x[srcs[r]]`` at rows ``r`` of a (T, C) 32-bit value, zero
+    where ``srcs[r] < 0`` (``len(srcs) <= T``).
+
+    A DMA addresses only whole (T, C) row tiles of a tiled HBM array (T =
+    the dtype's sublane count), so each row is fetched as the aligned tile
+    that holds it — all copies in flight at once — and rotated along the
+    sublanes so its row lands on row ``r`` (rotations take 32-bit data;
+    narrower dtypes widen exactly)."""
+    T, C = tiles.shape[1], tiles.shape[2]
+    wide = _wide(tiles.dtype)
+    copies = []
+    for r, s in enumerate(srcs):
+        t0 = pl.multiple_of(jnp.maximum(s, 0) // T * T, T)
+        cp = pltpu.make_async_copy(x_hbm.at[pl.ds(t0, T), :], tiles.at[r], sem.at[r])
+        pl.when(s >= 0)(cp.start)
+        copies.append(cp)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (T, C), 0)
+    acc = jnp.zeros((T, C), wide)
+    for r, (s, cp) in enumerate(zip(srcs, copies)):
+        pl.when(s >= 0)(cp.wait)
+        rolled = pltpu.roll(tiles[r].astype(wide), (r - s % T + T) % T, 0)
+        acc = jnp.where((sub == r) & (s >= 0), rolled, acc)
+    return acc
+
+
+def _gather_block_kernel(use_run: bool, idx_ref, x_hbm, o_ref, tiles, window, sem):
     """One grid step = one (br, C) output block.
 
-    Run detection first: when the block's br indices are a contiguous run,
-    ONE strided block copy fetches all rows; otherwise rows are copied
-    one DMA each, with negative (sentinel) indices zero-filled in VMEM.
-    ``use_run`` is static — False when br > n_src, where a br-row run
-    cannot exist (and the block-copy slice would be statically invalid).
+    ``idx_ref`` is this block's (br,) slice of the index table in SMEM (a
+    whole table can outgrow SMEM).  A DMA can only address whole (T, C)
+    row tiles of a tiled array (T = the dtype's sublane count), so source
+    rows are fetched as aligned tiles and rotated into place in VMEM:
+
+    * run path — when the block's br indices are a contiguous run, ONE
+      copy fetches the aligned (br + T)-row window around it and one
+      sublane rotation aligns the run (``use_run`` is static: False when
+      br is not whole tiles or the window does not fit the source);
+    * row path — otherwise, per group of T output rows, the T source
+      tiles are fetched concurrently and each is rotated so its row lands
+      on its output row (:func:`_fetch_rows`).  Negative (sentinel) rows
+      fetch nothing and stay zero, so each group is one aligned store.
     """
-    i = pl.program_id(0)
     br, C = o_ref.shape
-    base = i * br
-    start = idx_ref[base]
+    T = tiles.shape[1]
+    start = idx_ref[0]
+    wide = _wide(o_ref.dtype)
 
-    def _row_path(_):
-        def body(r, carry):
-            s = idx_ref[base + r]
+    def _row_path():
+        def store(off, size):
+            rows = _fetch_rows(x_hbm, tiles, sem, [idx_ref[off + r] for r in range(size)])
+            o_ref[pl.ds(off, size), :] = rows[:size].astype(o_ref.dtype)
 
-            @pl.when(s >= 0)
-            def _():
-                _row_dma(x_hbm, s, rows, r, sem)
+        g = min(T, br)  # whole groups at tile-aligned offsets, then the rest
 
-            @pl.when(s < 0)
-            def _():
-                rows[pl.ds(r, 1), :] = jnp.zeros((1, C), o_ref.dtype)
-
+        def body(i, carry):
+            store(pl.multiple_of(i * g, g), g)
             return carry
 
-        jax.lax.fori_loop(0, br, body, 0)
-        return 0
+        jax.lax.fori_loop(0, br // g, body, 0)
+        if br % g:
+            store(br - br % g, br % g)
 
-    if use_run:
+    if not use_run:
+        _row_path()
+        return
 
-        def _consecutive(r, ok):
-            return jnp.logical_and(ok, idx_ref[base + r] == start + r)
+    def _consecutive(r, ok):
+        return jnp.logical_and(ok, idx_ref[r] == start + r)
 
-        is_run = jax.lax.fori_loop(1, br, _consecutive, start >= 0)
+    is_run = jax.lax.fori_loop(1, br, _consecutive, start >= 0)
 
-        def _run_path(_):
-            cp = pltpu.make_async_copy(
-                x_hbm.at[pl.ds(start, br), :], rows.at[:, :], sem
-            )
-            cp.start()
-            cp.wait()
-            return 0
+    @pl.when(is_run)
+    def _run_path():
+        span = window.shape[0]
+        w0 = pl.multiple_of(jnp.minimum(start // T * T, x_hbm.shape[0] - span), T)
+        cp = pltpu.make_async_copy(x_hbm.at[pl.ds(w0, span), :], window, sem.at[0])
+        cp.start()
+        cp.wait()
+        shift = (span - (start - w0)) % span
+        o_ref[...] = pltpu.roll(window[...].astype(wide), shift, 0)[:br].astype(o_ref.dtype)
 
-        jax.lax.cond(is_run, _run_path, _row_path, 0)
-    else:
-        _row_path(0)
-    o_ref[...] = rows[...]
+    pl.when(jnp.logical_not(is_run))(_row_path)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -215,15 +254,17 @@ def gather_rows_blocked(
 
     The index table is reshaped to ``(nB, block_r)`` row blocks; each grid
     step moves ``block_r`` full-width rows off the HBM-resident source.
-    Contiguous index runs collapse to one strided block copy (run
-    detection), and sentinel rows are zero-filled in-kernel — no caller-
-    side sentinel-row concatenates.  Planned by
+    Contiguous index runs collapse to one block copy (run detection), and
+    sentinel rows are zero-filled in-kernel — no caller-side sentinel-row
+    concatenates.  Rows move as whole sublane tiles (see
+    :func:`_gather_block_kernel`); a source whose row count is not whole
+    tiles is zero-padded to whole tiles first.  Planned by
     :func:`repro.core.index_plan.plan_index_op`.
 
     This one kernel carries three plan semantics: masked ``gather``,
     ``scatter`` (via the inverted table), and the serving engine's
     ``ragged_rows`` unpack (DESIGN.md §12), where per-sequence packed
-    rows are contiguous runs — the run-detected strided-copy fast path —
+    rows are contiguous runs — the run-detected block-copy fast path —
     and the ``-1`` tail sentinels zero-fill each KV ring beyond its
     prompt length.
     """
@@ -238,21 +279,30 @@ def gather_rows_blocked(
     br = max(1, min(block_r, n_out))
     nB = cdiv(n_out, br)
     idxp = _pad_table(idx, nB * br)
+    T = sublanes(x.dtype)
+    if n_src % T:
+        x = jnp.pad(x, ((0, T - n_src % T), (0, 0)))
+    span = br + T
+    use_run = br % T == 0 and span <= x.shape[0]
 
     interpret = force_interpret() if interpret is None else interpret
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nB,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec((br, C), lambda i, idx_ref: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((br, C), x.dtype), pltpu.SemaphoreType.DMA],
-    )
     return pl.pallas_call(
-        functools.partial(_gather_block_kernel, br <= n_src),
-        grid_spec=grid_spec,
+        functools.partial(_gather_block_kernel, use_run),
+        grid=(nB,),
+        in_specs=[
+            pl.BlockSpec((None, None, br), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((T, T, C), x.dtype),
+            pltpu.VMEM((span if use_run else T, C), x.dtype),
+            pltpu.SemaphoreType.DMA((T,)),
+        ],
         out_shape=jax.ShapeDtypeStruct((n_out, C), x.dtype),
         interpret=interpret,
-    )(idxp, x)
+    )(idxp.reshape(nB, 1, br), x)
 
 
 def _gather_combine_kernel(back_ref, src_hbm, gates_ref, o_ref, rows, sem):
@@ -327,7 +377,7 @@ def gather_combine_blocked(
         num_scalar_prefetch=1,
         grid=(nT,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((bt, k), lambda i, back_ref: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bt, C), lambda i, back_ref: (i, 0)),

@@ -5,16 +5,19 @@ one array of length n*L (and back).  The CUDA version stages 8x8 blocks in
 shared memory with n*64 threads so that both the global load and the global
 store stay coalesced; the interleaving shuffle happens in shared memory.
 
-TPU version: the key observation is that for a column block of width
-``bc``, the interleaved output of that block is a *contiguous* run of
-``n*bc`` elements.  So:
+TPU version: each source is viewed as (L/128, 128) rows of one lane-width,
+and the interleaved output as (L/128, n*128): the n*128 outputs of source
+row s are exactly the n lane-rows that interleave the n source rows s.  So:
 
-  load   n lane-aligned tiles (1, bc)    — one per source array (coalesced),
-  shuffle in VMEM:  rows.T.reshape(n,bc) — the VREG transpose,
-  store  one lane-aligned tile (n, bc)   — contiguous in the output (coalesced).
+  load   n lane-aligned (S, 128) blocks     — one per source (coalesced),
+  shuffle in VMEM: output lane-column t of a row draws only from source
+         lanes [128t/n, 128(t+1)/n), i.e. from ONE vreg column, so it is n
+         in-register lane gathers plus a lane-parity select,
+  store  one lane-aligned (S, n*128) block  — contiguous in the output.
 
-Shared memory -> VMEM, warp shuffle -> VPU transpose, and the 8x8 block
-becomes an (n, bc) tile sized for (8,128) registers.
+Shared memory -> VMEM, warp shuffle -> single-vreg lane gather.  Gathers
+run in 32 bits (narrower dtypes widen exactly and narrow back), because
+Mosaic's lane gather wants indices and data of one bit width.
 """
 
 from __future__ import annotations
@@ -25,97 +28,115 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import LANES, VMEM_BUDGET, force_interpret
+from repro.kernels.tiling import LANES, force_interpret, sublanes
 
 
-def _pick_bc(L: int, n: int, itemsize: int) -> int:
-    """Largest power-of-two column block dividing L within VMEM budget.
-
-    Prefers lane multiples (>= 128); lengths with only a small power-of-two
-    factor still get a (narrower, slower) kernel block, and lengths with no
-    usable factor raise so dispatch falls back to the oracle.
-    """
-    if L == 0:
-        raise ValueError("empty arrays: no kernel block (oracle handles L=0)")
-    budget_elems = VMEM_BUDGET // (2 * itemsize * max(n, 1))
-    bc = 1
-    while bc * 2 <= min(budget_elems, 16384) and L % (bc * 2) == 0:
-        bc *= 2
-    if bc < 8:
-        raise ValueError(f"L={L} has no usable power-of-two block (got {bc})")
-    return bc
+def lane_aligned(length: int) -> bool:
+    """True when a length-``length`` source has a kernel view: a positive
+    whole number of 128-lane rows.  Dispatch routes other lengths to the
+    oracle before building a kernel."""
+    return length > 0 and length % LANES == 0
 
 
-def _interlace_kernel(n, bc, *refs):
+def _block_rows(rows: int, n: int, dtype) -> int:
+    """Source rows per grid step: about 2048 lane-rows of output per block,
+    sublane aligned, or every row when the sources are shorter."""
+    sl = sublanes(dtype)
+    return min(rows, max(sl, (2048 // max(n, 1)) // sl * sl))
+
+
+def _wide(dtype):
+    """The 32-bit type the lane gathers run in (exact for narrower types)."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
+def _interlace_kernel(n, *refs):
     o_ref = refs[-1]
-    rows = jnp.concatenate([r[...] for r in refs[:-1]], axis=0)  # (n, bc)
-    # out[j*n + k] = rows[k, j]  ==  row-major flat of rows.T
-    o_ref[...] = rows.T.reshape(n, bc)
+    rows = o_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    srcs = [r[...].astype(_wide(o_ref.dtype)) for r in refs[:-1]]
+    for t in range(n):
+        # output lane 128t + u holds source (pos % n) element pos // n
+        pos = LANES * t + lane
+        idx = pos // n
+        col = None
+        for k in range(n):
+            g = jnp.take_along_axis(srcs[k], idx, axis=1)
+            col = g if col is None else jnp.where(pos % n == k, g, col)
+        o_ref[:, LANES * t:LANES * (t + 1)] = col.astype(o_ref.dtype)
 
 
-def _deinterlace_kernel(n, bc, x_ref, *o_refs):
-    run = x_ref[...].reshape(bc, n)  # run[j, k] = flat[j*n + k]
+def _deinterlace_kernel(n, x_ref, *o_refs):
+    rows = x_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    cols = [
+        x_ref[:, LANES * t:LANES * (t + 1)].astype(_wide(x_ref.dtype))
+        for t in range(n)
+    ]
     for k, o_ref in enumerate(o_refs):
-        o_ref[...] = run[:, k].reshape(1, bc)
+        # element u of source k sits at interleaved lane u*n + k
+        pos = lane * n + k
+        idx = pos % LANES
+        out = None
+        for t in range(n):
+            g = jnp.take_along_axis(cols[t], idx, axis=1)
+            out = g if out is None else jnp.where(pos // LANES == t, g, out)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def interlace(
     arrays: tuple[jax.Array, ...],
     *,
-    block_c: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """n 1-D arrays (L,) -> (n*L,) with out[j*n + k] = arrays[k][j]."""
+    """n 1-D arrays (L,) -> (n*L,) with out[j*n + k] = arrays[k][j].
+    ``L`` must be :func:`lane_aligned`."""
     n = len(arrays)
     L = arrays[0].shape[0]
     for a in arrays:
         if a.shape != (L,) or a.dtype != arrays[0].dtype:
             raise ValueError("interlace requires same-shape/dtype 1-D arrays")
+    if not lane_aligned(L):
+        raise ValueError(f"L={L} is not a positive multiple of {LANES}")
     dtype = arrays[0].dtype
-    bc = block_c or _pick_bc(L, n, jnp.dtype(dtype).itemsize)
-    if L % bc:
-        raise ValueError(f"L={L} not divisible by block_c={bc}")
-    g = L // bc
-    views = [a.reshape(g, bc) for a in arrays]
-
+    rows = L // LANES
+    br = _block_rows(rows, n, dtype)
     interpret = force_interpret() if interpret is None else interpret
     out2d = pl.pallas_call(
-        functools.partial(_interlace_kernel, n, bc),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, bc), lambda i: (i, 0)) for _ in range(n)],
-        out_specs=pl.BlockSpec((n, bc), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g * n, bc), dtype),
+        functools.partial(_interlace_kernel, n),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[pl.BlockSpec((br, LANES), lambda i: (i, 0)) for _ in range(n)],
+        out_specs=pl.BlockSpec((br, n * LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n * LANES), dtype),
         interpret=interpret,
-    )(*views)
+    )(*(a.reshape(rows, LANES) for a in arrays))
     return out2d.reshape(n * L)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "block_c", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
 def deinterlace(
     x: jax.Array,
     n: int,
     *,
-    block_c: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, ...]:
-    """(n*L,) -> n arrays (L,): inverse of :func:`interlace`."""
+    """(n*L,) -> n arrays (L,): inverse of :func:`interlace`.  ``L`` must
+    be :func:`lane_aligned`."""
     if x.ndim != 1 or x.shape[0] % n:
         raise ValueError(f"bad shape {x.shape} for n={n}")
     L = x.shape[0] // n
-    bc = block_c or _pick_bc(L, n, jnp.dtype(x.dtype).itemsize)
-    if L % bc:
-        raise ValueError(f"L={L} not divisible by block_c={bc}")
-    g = L // bc
-    xview = x.reshape(g * n, bc)
-
+    if not lane_aligned(L):
+        raise ValueError(f"L={L} is not a positive multiple of {LANES}")
+    rows = L // LANES
+    br = _block_rows(rows, n, x.dtype)
     interpret = force_interpret() if interpret is None else interpret
     outs = pl.pallas_call(
-        functools.partial(_deinterlace_kernel, n, bc),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((n, bc), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, bc), lambda i: (i, 0)) for _ in range(n)],
-        out_shape=[jax.ShapeDtypeStruct((g, bc), x.dtype) for _ in range(n)],
+        functools.partial(_deinterlace_kernel, n),
+        grid=(pl.cdiv(rows, br),),
+        in_specs=[pl.BlockSpec((br, n * LANES), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((br, LANES), lambda i: (i, 0)) for _ in range(n)],
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), x.dtype) for _ in range(n)],
         interpret=interpret,
-    )(xview)
+    )(x.reshape(rows, n * LANES))
     return tuple(o.reshape(L) for o in outs)

@@ -10,10 +10,13 @@ Dispatch rules
 * ``REPRO_PALLAS_INTERPRET=1`` forces every op through the Pallas kernel in
   interpret mode — this is how the test suite validates kernel semantics
   on CPU.
-* Kernels have alignment preconditions (lane divisibility etc.).  When an
-  input violates them, the op silently falls back to the oracle — the
-  library never fails on an odd shape, it just loses the fast path (same
-  contract as the paper's library).
+* Kernels have alignment preconditions (lane divisibility, the TPU tiling
+  rule, a panel that fits VMEM).  Each op checks them through an explicit
+  predicate of the plan or the kernel module BEFORE it builds a kernel, and
+  routes an input that violates them to the oracle — the library never
+  fails on an odd shape, it just loses the fast path (same contract as the
+  paper's library).  Nothing here catches an error raised while a kernel
+  is built, lowered or compiled: a kernel the compiler refuses raises.
 """
 
 from __future__ import annotations
@@ -66,11 +69,8 @@ def _interpret() -> bool:
 
 def copy(x: Array) -> Array:
     """Materialized device copy (paper §III-A read/write kernel)."""
-    if use_pallas():
-        try:
-            return copy_k.copy(x, interpret=_interpret())
-        except ValueError:
-            pass
+    if use_pallas() and copy_k.has_view(x.shape):
+        return copy_k.copy(x, interpret=_interpret())
     return ref.copy(x)
 
 
@@ -265,10 +265,15 @@ def permute(x: Array, perm: Sequence[int], *, grid_order: str = "out") -> Array:
     return ref.permute(x, perm)
 
 
-def _apply_affine(x: Array, amap: affine.AffineMap, out_shape) -> Array:
-    """Shared affine-op dispatch: plan the map (analytic source), execute it
-    as ONE kernel pass, and reshape to the user-facing ``out_shape``."""
+def _apply_affine(x: Array, amap: affine.AffineMap, out_shape, oracle) -> Array:
+    """Shared affine-op dispatch: plan the map (analytic source) and run it
+    as ONE kernel pass reshaped to the user-facing ``out_shape`` — or call
+    ``oracle()`` where the plan routes there: ``mode="oracle"`` (no
+    single-pass lowering), or ``tpu_kernel=False`` when compiling for the
+    chip (the kernel breaks the TPU tiling rule)."""
     plan = plan_affine(amap, x.dtype)
+    if plan.mode == "oracle" or not (plan.tpu_kernel or _interpret()):
+        return oracle()
     return apply_plan(x, plan).reshape(out_shape)
 
 
@@ -276,14 +281,14 @@ def bit_reversal(x: Array, *, axis: int = 0) -> Array:
     """Bit-reversal reorder along ``axis`` (FFT layouts, paper's reorder
     class): element ``i`` moves to bit-reversed index.  Affine route: the
     axis is digit-split into base-2 digits whose order is reversed — a
-    clean digit permutation, ONE pallas_call, no index table."""
+    clean digit permutation, ONE pallas_call, no index table.  A
+    non-power-of-two axis raises ValueError."""
     axis = axis % max(x.ndim, 1)
     if use_pallas() and x.size:
-        try:
-            amap = affine.bit_reversal_map(x.shape, axis=axis)
-            return _apply_affine(x, amap, x.shape)
-        except ValueError:
-            pass  # non-power-of-2 axis or unlowerable: oracle fallback
+        amap = affine.bit_reversal_map(x.shape, axis=axis)
+        return _apply_affine(
+            x, amap, x.shape, lambda: ref.bit_reversal(x, axis=axis)
+        )
     return ref.bit_reversal(x, axis=axis)
 
 
@@ -293,19 +298,19 @@ def strided_gather(x: Array, stride: int, *, phase: int = 0, axis: int = 0) -> A
     When ``stride`` divides the axis (and ``phase < stride``) this lowers
     through the affine planner: the axis digit-splits into
     ``(n // stride, stride)`` with the stride digit pinned at ``phase`` —
-    a windowed affine map, ONE pallas_call, no materialized slice."""
+    a windowed affine map, ONE pallas_call, no materialized slice.  Other
+    strides run the oracle."""
     if stride <= 0:
         raise ValueError(f"stride must be positive, got {stride}")
     axis = axis % max(x.ndim, 1)
-    if use_pallas() and x.size:
-        try:
-            amap = affine.strided_map(x.shape, axis=axis, stride=stride, phase=phase)
-            out_shape = (
-                x.shape[:axis] + (x.shape[axis] // stride,) + x.shape[axis + 1:]
-            )
-            return _apply_affine(x, amap, out_shape)
-        except ValueError:
-            pass  # stride/phase not digit-splittable: oracle fallback
+    splits = x.ndim > 0 and x.shape[axis] % stride == 0 and 0 <= phase < stride
+    if use_pallas() and x.size and splits:
+        amap = affine.strided_map(x.shape, axis=axis, stride=stride, phase=phase)
+        out_shape = x.shape[:axis] + (x.shape[axis] // stride,) + x.shape[axis + 1:]
+        return _apply_affine(
+            x, amap, out_shape,
+            lambda: ref.strided_gather(x, stride, phase=phase, axis=axis),
+        )
     return ref.strided_gather(x, stride, phase=phase, axis=axis)
 
 
@@ -317,10 +322,10 @@ def diagonal_reorder(x: Array) -> Array:
     if x.ndim < 2:
         raise ValueError("diagonal_reorder wants rank >= 2")
     if use_pallas() and x.size:
-        try:
-            return _apply_affine(x, affine.diagonal_map(x.shape), x.shape)
-        except ValueError:
-            pass
+        return _apply_affine(
+            x, affine.diagonal_map(x.shape), x.shape,
+            lambda: ref.diagonal_reorder(x),
+        )
     return ref.diagonal_reorder(x)
 
 
@@ -334,11 +339,8 @@ def shuffle(x: Array, seed: int = 0) -> Array:
     yields the same permutation; the oracle path materializes it as a
     gather table instead."""
     if use_pallas() and x.size and x.ndim >= 1 and x.shape[0] > 1:
-        try:
-            amap = affine.shuffle_map(x.shape[0], payload=x.shape[1:], seed=seed)
-            return _apply_affine(x, amap, x.shape)
-        except ValueError:
-            pass
+        amap = affine.shuffle_map(x.shape[0], payload=x.shape[1:], seed=seed)
+        return _apply_affine(x, amap, x.shape, lambda: ref.shuffle(x, seed=seed))
     return ref.shuffle(x, seed=seed)
 
 
@@ -373,17 +375,14 @@ def reorder_nm(
             min(max(int(b), 0), x.shape[k] - int(sizes_l[k]))
             for k, b in enumerate(base_l)
         )
-        try:
+        sizes_t = tuple(int(s) for s in sizes_l)
+        interp = _interpret()
+        if rnd_k.window_blocks(
+            x.shape, x.dtype, tuple(full_perm), base_c, sizes_t, tpu_rule=not interp
+        ) is not None:
             moved = rnd_k.reorder_window(
-                x,
-                tuple(full_perm),
-                base_c,
-                tuple(int(s) for s in sizes_l),
-                interpret=_interpret(),
+                x, tuple(full_perm), base_c, sizes_t, interpret=interp
             )
-        except ValueError:
-            pass  # base too misaligned for fused blocks: two-pass fallback
-        else:
             return moved.reshape(out_shape)
     # runtime (traced) or misaligned base: slice, then permute via kernel
     window = jax.lax.dynamic_slice(x, base_l, sizes_l)
@@ -398,27 +397,35 @@ def interlace(arrays: Sequence[Array]) -> Array:
     same = arrays and arrays[0].ndim >= 1 and all(
         a.shape == arrays[0].shape and a.dtype == arrays[0].dtype for a in arrays
     )
-    if use_pallas() and same:
+    if use_pallas() and same and il_k.lane_aligned(arrays[0].size):
         lead, last = arrays[0].shape[:-1], arrays[0].shape[-1]
         flat = tuple(a.reshape(-1) for a in arrays)
-        try:
-            out = il_k.interlace(flat, interpret=_interpret())
-        except ValueError:
-            return ref.interlace(arrays)
+        out = il_k.interlace(flat, interpret=_interpret())
         return out.reshape(*lead, last * len(arrays))
     return ref.interlace(arrays)  # mismatched inputs raise in the oracle
 
 
 def deinterlace(x: Array, n: int) -> list[Array]:
     """Inverse of :func:`interlace` along the last axis (N-D supported)."""
-    if use_pallas() and x.ndim >= 1 and x.shape[-1] % n == 0:
+    if (
+        use_pallas() and x.ndim >= 1 and x.shape[-1] % n == 0
+        and il_k.lane_aligned(x.size // n)
+    ):
         lead, last = x.shape[:-1], x.shape[-1]
-        try:
-            outs = il_k.deinterlace(x.reshape(-1), n, interpret=_interpret())
-        except ValueError:
-            return ref.deinterlace(x, n)
+        outs = il_k.deinterlace(x.reshape(-1), n, interpret=_interpret())
         return [o.reshape(*lead, last // n) for o in outs]
     return ref.deinterlace(x, n)
+
+
+def _fused_ok(x: Array, radii, boundary: str, **kw) -> bool:
+    """The stencil precondition: the Pallas path is on, the grid is a
+    non-empty 2-D array, and the fused pipeline has a panel for it."""
+    return (
+        use_pallas() and boundary in st_k.BOUNDARIES and x.ndim == 2
+        and x.size > 0
+        and st_k.fused_panel(*x.shape, x.dtype, tuple(radii), boundary, **kw)
+        is not None
+    )
 
 
 def stencil2d(
@@ -429,13 +436,11 @@ def stencil2d(
     boundary: str = "zero",
 ) -> Array:
     """Single weighted-sum stencil sweep (any of the four boundary modes)."""
-    if use_pallas() and boundary in st_k.BOUNDARIES and x.ndim == 2 and x.size:
-        try:
-            return st_k.stencil2d(
-                x, offsets, weights, boundary=boundary, interpret=_interpret()
-            )
-        except ValueError:
-            pass  # no fused configuration for this shape: oracle fallback
+    radius = max((max(abs(dy), abs(dx)) for dy, dx in offsets), default=0)
+    if _fused_ok(x, (radius,), boundary):
+        return st_k.stencil2d(
+            x, offsets, weights, boundary=boundary, interpret=_interpret()
+        )
     return ref.stencil2d(x, offsets, weights, boundary=boundary)
 
 
@@ -447,13 +452,10 @@ def stencil2d_functor(
     boundary: str = "zero",
 ) -> Array:
     """Single generic-functor stencil sweep (trace-time specialization)."""
-    if use_pallas() and boundary in st_k.BOUNDARIES and x.ndim == 2 and x.size:
-        try:
-            return st_k.stencil2d_functor(
-                x, functor, radius, boundary=boundary, interpret=_interpret()
-            )
-        except ValueError:
-            pass
+    if _fused_ok(x, (int(radius),), boundary):
+        return st_k.stencil2d_functor(
+            x, functor, radius, boundary=boundary, interpret=_interpret()
+        )
     return ref.stencil2d_functor(x, functor, radius, boundary=boundary)
 
 
@@ -470,9 +472,10 @@ def stencil_program(
     """Execute a compiled stencil program (tuple of (functor, radius)
     stages — see ``core.stencil.StencilPlan.stages_exec``).
 
-    Fused temporal-blocking kernel on the Pallas path; per-sweep oracle
-    sweeps otherwise (or when the planner routed the program to the
-    reference path, ``fused=False``).
+    Fused temporal-blocking kernel on the Pallas path when
+    :func:`repro.kernels.stencil2d.fused_panel` has a panel for the grid;
+    per-sweep oracle sweeps otherwise (or when the planner routed the
+    program to the reference path, ``fused=False``).
 
     ``window=(row0, global_rows)`` runs the program in global-row-window
     mode (§10 halo exchange): ``x`` is a halo-extended shard whose row 0
@@ -484,21 +487,21 @@ def stencil_program(
     if window is not None and aux is not None:
         raise ValueError("window mode does not support aux operands")
     row0, global_rows = (None, None) if window is None else window
-    if fused and use_pallas() and x.size:
-        try:
-            return st_k.stencil2d_pipeline(
-                x,
-                stages,
-                boundary=boundary,
-                aux=aux,
-                block_rows=block_rows,
-                row0=row0,
-                global_rows=global_rows,
-                halo_resident=window is not None,
-                interpret=_interpret(),
-            )
-        except ValueError:
-            pass  # shape constraints changed underfoot: oracle fallback
+    radii = tuple(int(r) for _, r in stages)
+    if fused and _fused_ok(
+        x, radii, boundary, block_rows=block_rows, halo_resident=window is not None
+    ):
+        return st_k.stencil2d_pipeline(
+            x,
+            stages,
+            boundary=boundary,
+            aux=aux,
+            block_rows=block_rows,
+            row0=row0,
+            global_rows=global_rows,
+            halo_resident=window is not None,
+            interpret=_interpret(),
+        )
     if window is not None:
         return ref.stencil_pipeline_window(
             x, stages, boundary=boundary, row0=row0, global_rows=global_rows

@@ -40,12 +40,9 @@ def _transpose_kernel(x_ref, o_ref):
     o_ref[0, :, :] = x_ref[0, :, :].T
 
 
-def _dim_semantics(n: int, parallel: bool):
+def _dim_semantics(n: int, parallel: bool) -> pltpu.CompilerParams:
     kind = pltpu.PARALLEL if parallel else pltpu.ARBITRARY
-    try:
-        return pltpu.CompilerParams(dimension_semantics=(kind,) * n)
-    except Exception:  # pragma: no cover - API drift guard
-        return None
+    return pltpu.CompilerParams(dimension_semantics=(kind,) * n)
 
 
 @functools.partial(
@@ -85,8 +82,6 @@ def transpose2d_batched(
             return (b, j, i)
 
     interpret = force_interpret() if interpret is None else interpret
-    params = _dim_semantics(3, parallel=not diagonal)
-    kwargs = {"compiler_params": params} if params is not None else {}
     return pl.pallas_call(
         _transpose_kernel,
         grid=(B, nR, nC),
@@ -94,7 +89,7 @@ def transpose2d_batched(
         out_specs=pl.BlockSpec((1, bc, br), out_map),
         out_shape=jax.ShapeDtypeStruct((B, C, R), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_dim_semantics(3, parallel=not diagonal),
     )(x)
 
 
@@ -139,8 +134,6 @@ def transpose2d_batched_vec(
         return (b, j, i, v)
 
     interpret = force_interpret() if interpret is None else interpret
-    params = _dim_semantics(4, parallel=True)
-    kwargs = {"compiler_params": params} if params is not None else {}
     return pl.pallas_call(
         _transpose_vec_kernel,
         grid=(B, nR, nC, nV),
@@ -148,7 +141,7 @@ def transpose2d_batched_vec(
         out_specs=pl.BlockSpec((1, bc, br, bv), out_map),
         out_shape=jax.ShapeDtypeStruct((B, C, R, V), x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_dim_semantics(4, parallel=True),
     )(x)
 
 

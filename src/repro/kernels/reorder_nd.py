@@ -15,7 +15,10 @@ fastest-changing output dim* — is kept intact.  What changes on TPU:
 * Exactly **two axes are blocked**: the input-fastest axis (lane dim of the
   load tile) and the axis that becomes output-fastest (lane dim of the
   store tile).  All other axes are batch.  Both DMAs therefore move full
-  lane-aligned tiles — coalesced-on-both-sides, per the paper.
+  lane-aligned tiles — coalesced-on-both-sides, per the paper.  The TPU
+  tiling rule also needs the second-minor axis of each block sublane-deep,
+  so a batch axis in that position gets a sublane block too (see
+  :func:`_tile_blocks`).
 * If the permutation *preserves* the fastest axis ("copy mode"), the kernel
   degenerates to a blocked gather of contiguous rows — the paper's N-to-M
   case with preserved dim-0.
@@ -30,6 +33,7 @@ window base folded into the input index map (DESIGN.md §6).
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -39,6 +43,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import (
+    LANES,
+    VMEM_BYTES,
     align_block,
     cdiv,
     force_interpret,
@@ -48,15 +54,32 @@ from repro.kernels.tiling import (
 )
 
 
-def _permute_kernel(perm, x_ref, o_ref):
-    o_ref[...] = jnp.transpose(x_ref[...], perm)
+def _permute_kernel(perm, extra, x_ref, o_ref):
+    """Move one block: ``o = transpose(x, perm)`` over the kernel's view.
+
+    ``extra`` lists the view axes that carry sublane-deep batch blocks.  A
+    whole-block N-D transpose of such a block makes Mosaic stage ~25x its
+    size in VMEM, so those axes are unrolled instead: each step moves one
+    2-D (rows, lanes) plane, transposed when the lane axis changes."""
+    if not extra:
+        o_ref[...] = jnp.transpose(x_ref[...], perm)
+        return
+    swap = perm[-1] != len(perm) - 1
+    for pos in itertools.product(*(range(x_ref.shape[a]) for a in extra)):
+        at = dict(zip(extra, pos))
+        plane = x_ref[tuple(at.get(a, slice(None)) for a in range(len(perm)))]
+        o_ref[tuple(at.get(a, slice(None)) for a in perm)] = plane.T if swap else plane
 
 
-def _dim_semantics(n: int):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=(pltpu.ARBITRARY,) * n)
-    except Exception:  # pragma: no cover
-        return None
+def _compiler_params(n: int, block_bytes: int) -> pltpu.CompilerParams:
+    """Sequential grid semantics, and a scoped-VMEM limit that holds the
+    double-buffered in + out blocks (raised above the compiler default only
+    when the blocks need it)."""
+    need = 4 * block_bytes + (2 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=(pltpu.ARBITRARY,) * n,
+        vmem_limit_bytes=max(need, VMEM_BYTES),
+    )
 
 
 def _movement_axes(perm: tuple[int, ...]) -> tuple[int | None, int, bool]:
@@ -72,10 +95,64 @@ def _movement_axes(perm: tuple[int, ...]) -> tuple[int | None, int, bool]:
     return r_in, c_in, transpose_mode
 
 
-def _align_block(block: int, offset: int) -> int:
-    """Largest block <= ``block`` (by halving) that divides ``offset``, so a
-    window base can ride in the index map as a whole number of blocks."""
-    return align_block(block, offset)
+#: per-buffer byte cap for a reorder block: the plane tile shrinks toward
+#: it when batch axes take sublane blocks (see :func:`_tile_blocks`)
+_BLOCK_BYTES = 2 << 20
+
+
+def _tile_blocks(
+    perm: tuple[int, ...],
+    shape: tuple[int, ...],
+    sizes: tuple[int, ...],
+    base: tuple[int, ...],
+    dtype,
+    br: int,
+    bc: int,
+    *,
+    tpu_rule: bool = True,
+) -> list[int] | None:
+    """Per-input-axis block sizes of ``transpose(x[base:base+sizes], perm)``
+    or None when every window base is not a whole number of blocks, or
+    (``tpu_rule``) the blocks break the TPU tiling rule on either side.
+
+    The two plane axes carry the (br, bc) tile.  The rule also binds the
+    second-minor axis of the input block (axis N-2) and of the output block
+    (input axis ``perm[-2]``): when either is a batch axis it takes a
+    sublane-deep block instead of a unit one, and the plane tile halves
+    (keeping its own alignment) until the block fits ``_BLOCK_BYTES``."""
+    N = len(perm)
+    r_in, c_in, transpose_mode = _movement_axes(perm)
+    blocks = [1] * N
+    blocks[c_in] = bc
+    if r_in is not None:
+        blocks[r_in] = br
+    if N >= 2:
+        sl = sublanes(dtype)
+        for k in {N - 2, perm[-2]} - {r_in, c_in}:
+            blocks[k] = align_block(min(sl, sizes[k]), base[k])
+        # halve the plane tile toward the byte cap, keeping each side legal
+        itemsize = jnp.dtype(dtype).itemsize
+        need = {c_in: LANES, r_in: LANES if transpose_mode else sl}
+        while math.prod(blocks) * itemsize > _BLOCK_BYTES:
+            can = [a for a in need if blocks[a] > 1 and (blocks[a] // 2) % need[a] == 0]
+            if not can:
+                break
+            k = max(can, key=lambda a: blocks[a])
+            blocks[k] //= 2
+    out_sizes = [sizes[p] for p in perm]
+
+    def legal(b: int, dim: int, mult: int) -> bool:
+        return b == dim or b % mult == 0
+
+    ok = all(base[k] % blocks[k] == 0 for k in range(N))
+    if not tpu_rule:
+        return blocks if ok else None
+    ok = ok and legal(blocks[N - 1], shape[N - 1], LANES)
+    ok = ok and legal(blocks[perm[-1]], out_sizes[-1], LANES)
+    if N >= 2:
+        ok = ok and legal(blocks[N - 2], shape[N - 2], 8)
+        ok = ok and legal(blocks[perm[-2]], out_sizes[-2], 8)
+    return blocks if ok else None
 
 
 def _reorder_call(
@@ -83,24 +160,18 @@ def _reorder_call(
     perm: tuple[int, ...],
     base: tuple[int, ...],
     sizes: tuple[int, ...],
-    br: int,
-    bc: int,
-    r_in: int | None,
-    c_in: int,
+    blocks: list[int],
     grid_order: str,
     interpret: bool,
 ) -> jax.Array:
     """Shared grid builder: ``transpose(x[base : base+sizes], perm)`` as one
-    pallas_call.  Batch axes use unit blocks (any base offset is exact); the
-    two blocked plane axes must have block-aligned bases (see callers)."""
+    pallas_call over the :func:`_tile_blocks` blocks.  The two plane axes
+    ride the (i, j) grid axes; every other axis walks the flattened batch
+    grid (unit-block batch axes are squeezed out of the kernel's view)."""
     N = x.ndim
     W = sizes
     out_shape = tuple(W[p] for p in perm)
-
-    blocks = [1] * N
-    blocks[c_in] = bc
-    if r_in is not None:
-        blocks[r_in] = br
+    r_in, c_in, _ = _movement_axes(perm)
     nblocks = [cdiv(W[k], blocks[k]) for k in range(N)]
     offs = [base[k] // blocks[k] for k in range(N)]  # exact: blocks aligned
 
@@ -139,27 +210,31 @@ def _reorder_call(
         c = win_coords(g, i, j)
         return tuple(c[p] for p in perm)
 
-    in_block = tuple(blocks)
-    out_block = tuple(blocks[p] for p in perm)
+    # unit blocks are squeezed, so the kernel transposes only the kept axes
+    kept = [k for k in range(N) if blocks[k] > 1 or k in plane]
+    in_block = tuple(blocks[k] if k in kept else None for k in range(N))
+    out_block = tuple(in_block[p] for p in perm)
+    kernel_perm = tuple(kept.index(p) for p in perm if p in kept)
+    extra = tuple(i for i, k in enumerate(kept) if k not in plane)
     grid_r = nblocks[r_in] if r_in is not None else 1
+    block_bytes = math.prod(blocks) * jnp.dtype(x.dtype).itemsize
 
-    params = _dim_semantics(3)
-    kwargs = {"compiler_params": params} if params is not None else {}
     return pl.pallas_call(
-        functools.partial(_permute_kernel, perm),
+        functools.partial(_permute_kernel, kernel_perm, extra),
         grid=(G, grid_r, nblocks[c_in]),
         in_specs=[pl.BlockSpec(in_block, in_map)],
         out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(3, block_bytes),
     )(x)
 
 
 def _plan_blocks(
     perm: tuple[int, ...], sizes: tuple[int, ...], dtype
-) -> tuple[int, int, int | None, int, bool]:
-    """Tile the movement plane of ``perm`` over (window) ``sizes``."""
+) -> tuple[int, int]:
+    """Heuristic (br, bc) tile of the movement plane of ``perm`` over
+    (window) ``sizes``."""
     r_in, c_in, transpose_mode = _movement_axes(perm)
     R = sizes[r_in] if r_in is not None else 1
     C = sizes[c_in]
@@ -167,7 +242,7 @@ def _plan_blocks(
         plan = plan_transpose_tiles(R, C, dtype)
     else:
         plan = plan_copy_tiles(R, C, dtype)
-    return plan.block_r, plan.block_c, r_in, c_in, transpose_mode
+    return plan.block_r, plan.block_c
 
 
 @functools.partial(
@@ -198,13 +273,18 @@ def permute_nd(
         # identity: fall through to a plain copy (still a kernel-shaped op)
         return x + jnp.zeros((), x.dtype)
 
-    pr, pc, r_in, c_in, _ = _plan_blocks(perm, x.shape, x.dtype)
+    r_in, c_in, _ = _movement_axes(perm)
+    pr, pc = _plan_blocks(perm, x.shape, x.dtype)
     br = min(block_r or pr, x.shape[r_in]) if r_in is not None else 1
     bc = min(block_c or pc, x.shape[c_in])
     interpret = force_interpret() if interpret is None else interpret
-    return _reorder_call(
-        x, perm, (0,) * N, x.shape, br, bc, r_in, c_in, grid_order, interpret
+    base = (0,) * N
+    blocks = _tile_blocks(
+        perm, x.shape, x.shape, base, x.dtype, br, bc, tpu_rule=not interpret
     )
+    if blocks is None:
+        raise ValueError(f"tile ({br}, {bc}) breaks the TPU tiling rule for {x.shape}")
+    return _reorder_call(x, perm, base, x.shape, blocks, grid_order, interpret)
 
 
 def _affine_body(perm_axes, out_block, rshift, x_ref, o_ref):
@@ -215,18 +295,62 @@ def _affine_body(perm_axes, out_block, rshift, x_ref, o_ref):
     if rshift is not None:
         C, rot, sign, kind, weight, radix, br = rshift
         rows = max(blk.size // C, 1)
-        plane = blk.reshape(rows, C)
+        # out[col] = plane[(col + rot + sign * coord) % C]: a lane rotation
+        # by -(rot + sign * coord), strided by -sign per row on the row kind
         if kind == "row":
-            coord = pl.program_id(1) * br + lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0
-            )
+            coord0, stride = pl.program_id(1) * br, (-sign) % C
         else:  # batch digit: one coordinate per grid step
-            coord = lax.rem(pl.program_id(0) // weight, radix)
-        col = lax.broadcasted_iota(jnp.int32, (rows, C), 1)
-        src_col = jnp.mod(col + rot + sign * coord, C)
-        plane = jnp.take_along_axis(plane, src_col, axis=1)
+            coord0, stride = lax.rem(pl.program_id(0) // weight, radix), 0
+        shift = lax.rem(lax.rem(-(rot + sign * coord0), C) + C, C)
+        plane = blk.reshape(rows, C)
+        wide = jnp.float32 if jnp.issubdtype(plane.dtype, jnp.floating) else jnp.int32
+        kw = {"stride": stride, "stride_axis": 0} if stride else {}
+        # Mosaic rotates only 32-bit data: narrower types widen exactly
+        plane = pltpu.roll(plane.astype(wide), shift, 1, **kw).astype(blk.dtype)
         blk = plane.reshape(out_block)
     o_ref[...] = blk
+
+
+def _affine_blocks(ex, block_r: int | None, block_c: int | None):
+    """(br, bc, in_block, out_block) of the affine kernel for the derived
+    execution ``ex`` (tile overrides ``block_r`` / ``block_c``)."""
+    m = ex.amap
+    outd = m.out_digits
+    jr, jc = ex.jr, ex.jc
+    R = outd[jr] if jr is not None else 1
+    C = outd[jc]
+    br = align_block(min(block_r or ex.block_r, R),
+                     m.base[m.src[jr]]) if jr is not None else 1
+    if ex.resident_skew:
+        bc = C  # lane digit fully resident (shifted in-kernel)
+    else:
+        bc = align_block(min(block_c or ex.block_c, C), m.base[m.src[jc]])
+    in_block = [1] * len(m.in_digits)
+    out_block = [1] * len(outd)
+    if jr is not None:
+        in_block[m.src[jr]] = out_block[jr] = br
+    in_block[m.src[jc]] = out_block[jc] = bc
+    return br, bc, tuple(in_block), tuple(out_block)
+
+
+def affine_tiling_ok(ex) -> bool:
+    """True when the kernel for the derived execution ``ex``
+    (``affine.derive``) satisfies the TPU tiling rule: the last two dims
+    of its input and output blocks are (8, 128)-divisible or whole.  The
+    planner records it on every affine plan (``RearrangePlan.tpu_kernel``);
+    dispatch sends a map without it to the oracle on the chip, where the
+    compiler would refuse the kernel."""
+    if ex.mode != "affine":
+        return True  # permutation class: permute_nd tiles it legally
+    _, _, in_block, out_block = _affine_blocks(ex, None, None)
+
+    def legal(block, dims):
+        ok = block[-1] == dims[-1] or block[-1] % LANES == 0
+        if len(dims) >= 2:
+            ok = ok and (block[-2] == dims[-2] or block[-2] % 8 == 0)
+        return ok
+
+    return legal(in_block, ex.amap.in_digits) and legal(out_block, ex.amap.out_digits)
 
 
 @functools.partial(
@@ -251,9 +375,9 @@ def reorder_affine(
     per-digit mod-affine arithmetic evaluated *in the scalar core* inside
     the BlockSpec index_map — the affine generalization of ``permute_nd``'s
     mixed-radix decomposition, still zero memory traffic for metadata.  A
-    skewed lane digit stays fully resident and is shifted in-kernel
-    (`take_along_axis` over the lane axis).  Raises ValueError when the map
-    has no single-pass lowering; dispatch falls back to the oracle."""
+    skewed lane digit stays fully resident and is shifted in-kernel (a
+    strided lane rotation).  Raises ValueError when the map has no
+    single-pass lowering; the planner routes such maps to the oracle."""
     from repro.core import affine as af  # lazy: affine imports tiling only
 
     ex = af.derive(amap, x.dtype, grid_order)
@@ -273,12 +397,7 @@ def reorder_affine(
     jr, jc = ex.jr, ex.jc
     R = outd[jr] if jr is not None else 1
     C = outd[jc]
-    br = align_block(min(block_r or ex.block_r, R),
-                     m.base[m.src[jr]]) if jr is not None else 1
-    if ex.resident_skew:
-        bc = C  # lane digit fully resident (shifted in-kernel)
-    else:
-        bc = align_block(min(block_c or ex.block_c, C), m.base[m.src[jc]])
+    br, bc, in_block, out_block = _affine_blocks(ex, block_r, block_c)
 
     batch = [j for j in range(mo) if j != jr and j != jc]
     if grid_order == "in":
@@ -327,15 +446,6 @@ def reorder_affine(
             for jd in range(mo)
         )
 
-    in_block = [1] * ni
-    if jr is not None:
-        in_block[m.src[jr]] = br
-    in_block[m.src[jc]] = C if ex.resident_skew else bc
-    out_block = [1] * mo
-    if jr is not None:
-        out_block[jr] = br
-    out_block[jc] = C if ex.resident_skew else bc
-
     # in-block axes -> output digit order (trailing axes are unit window /
     # pinned digits, absorbed by the reshape)
     perm_axes = [m.src[jd] for jd in range(mo)]
@@ -356,18 +466,17 @@ def reorder_affine(
             raise ValueError("lane digit skewed off an undecodable digit")
 
     interpret = force_interpret() if interpret is None else interpret
-    params = _dim_semantics(3)
-    kwargs = {"compiler_params": params} if params is not None else {}
+    block_bytes = math.prod(out_block) * jnp.dtype(x.dtype).itemsize
     out = pl.pallas_call(
         functools.partial(
-            _affine_body, tuple(perm_axes), tuple(out_block), rshift
+            _affine_body, tuple(perm_axes), out_block, rshift
         ),
         grid=(G, cdiv(R, br) if jr is not None else 1, cdiv(C, bc)),
-        in_specs=[pl.BlockSpec(tuple(in_block), in_map)],
-        out_specs=pl.BlockSpec(tuple(out_block), out_map),
+        in_specs=[pl.BlockSpec(in_block, in_map)],
+        out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(outd, x.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=_compiler_params(3, block_bytes),
     )(x)
     return out.reshape(amap.out_digits)
 
@@ -409,23 +518,38 @@ def reorder_window(
                 f"window [{base[k]}, {base[k]}+{sizes[k]}) exceeds axis {k} "
                 f"of shape {x.shape}"
             )
-    W = tuple(int(s) for s in sizes)
-
-    pr, pc, r_in, c_in, _ = _plan_blocks(perm, W, x.dtype)
-    br = _align_block(min(pr, W[r_in]), base[r_in]) if r_in is not None else 1
-    bc = _align_block(min(pc, W[c_in]), base[c_in])
-    # quality gate: misaligned bases shrink plane blocks; below the dtype's
-    # sublane floor the fused pass would be slower than slice-then-permute
-    sl = sublanes(x.dtype)
-    floor_r = min(sl, W[r_in]) if r_in is not None else 1
-    floor_c = min(sl, W[c_in])
-    if (r_in is not None and br < floor_r) or bc < floor_c:
-        raise ValueError(
-            f"window base {base} too misaligned for fused blocks "
-            f"({br}x{bc} < {floor_r}x{floor_c})"
-        )
     interpret = force_interpret() if interpret is None else interpret
-    return _reorder_call(
-        x, perm, tuple(int(b) for b in base), W, br, bc, r_in, c_in,
-        grid_order, interpret,
-    )
+    base = tuple(int(b) for b in base)
+    W = tuple(int(s) for s in sizes)
+    blocks = window_blocks(x.shape, x.dtype, perm, base, W, tpu_rule=not interpret)
+    if blocks is None:
+        raise ValueError(f"window base {base} too misaligned for fused blocks")
+    return _reorder_call(x, perm, base, W, blocks, grid_order, interpret)
+
+
+def window_blocks(
+    shape: tuple[int, ...],
+    dtype,
+    perm: tuple[int, ...],
+    base: tuple[int, ...],
+    sizes: tuple[int, ...],
+    *,
+    tpu_rule: bool,
+) -> list[int] | None:
+    """The fused windowed reorder's blocks, or None when the window cannot
+    run as one pass — the precondition dispatch checks before choosing the
+    fused form over slice-then-permute.
+
+    Blocked plane axes shrink (by halving) until the base offset is
+    block-aligned.  None when that drives a plane block below the dtype's
+    sublane floor (the fused pass would issue element-granular DMAs), or,
+    with ``tpu_rule``, when the blocks break the TPU compiler's tiling
+    rule (the interpreter has no such rule)."""
+    r_in, c_in, _ = _movement_axes(perm)
+    pr, pc = _plan_blocks(perm, sizes, dtype)
+    br = align_block(min(pr, sizes[r_in]), base[r_in]) if r_in is not None else 1
+    bc = align_block(min(pc, sizes[c_in]), base[c_in])
+    sl = sublanes(dtype)
+    if r_in is not None and br < min(sl, sizes[r_in]) or bc < min(sl, sizes[c_in]):
+        return None
+    return _tile_blocks(perm, shape, sizes, base, dtype, br, bc, tpu_rule=tpu_rule)

@@ -101,8 +101,9 @@ def pick_panel(
       VMEM-resident and the wrap halo is built from resident rows.
 
     Raises ``ValueError`` when no fused configuration exists for the shape
-    (the dispatch layer then falls back to per-sweep sweeps — the library
-    never fails on an awkward shape, it just loses the fast path).
+    (:func:`fused_panel` turns that into the precondition dispatch checks:
+    the library never fails on an awkward shape, it runs per-sweep oracle
+    sweeps instead).
     """
     sl = sublanes(dtype)
     itemsize = jnp.dtype(dtype).itemsize
@@ -154,6 +155,38 @@ def pick_panel(
             f"fused stencil panel ({br}+2*{R}, {W}) exceeds the VMEM budget"
         )
     return br, rp, wrap_local
+
+
+def fused_panel(
+    H: int,
+    W: int,
+    dtype,
+    radii: tuple[int, ...],
+    boundary: str,
+    *,
+    block_rows: int | None = None,
+    halo_resident: bool = False,
+) -> tuple[int, int, bool] | None:
+    """The fused pipeline's panel ``(block_rows, halo_block_rows,
+    wrap_local)`` for a program with stage ``radii``, or None when no fused
+    configuration exists — the precondition dispatch and the stencil
+    planner check before choosing the kernel over per-sweep oracle sweeps.
+
+    None when a stage's column halo does not fit the row width (reflect
+    needs ``W >= r + 1``, periodic ``W >= r``) or :func:`pick_panel` finds
+    no panel.  ``halo_resident`` marks periodic wrap rows as physically
+    present (the §10 ring exchange delivered them), so the panel geometry
+    uses the clamped (non-wrapping) family."""
+    for r in radii:
+        if r and boundary == "reflect" and W < r + 1:
+            return None
+        if r and boundary == "periodic" and W < r:
+            return None
+    geo_boundary = "zero" if (halo_resident and boundary == "periodic") else boundary
+    try:
+        return pick_panel(H, W, dtype, sum(radii), geo_boundary, block_rows)
+    except ValueError:
+        return None
 
 
 def _pipeline_kernel(
@@ -239,10 +272,13 @@ def _pipeline_kernel(
 
                 def _regather(c, _pos=pos, _cols=cols):
                     sel = (_cols == _pos).astype(jnp.float32)
+                    # full f32 precision: the one-hot product must copy
+                    # rows exactly (a bf16 MXU pass would round them)
                     return jax.lax.dot_general(
                         sel,
                         c.astype(jnp.float32),
                         (((1,), (0,)), ((), ())),
+                        precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=jnp.float32,
                     ).astype(c.dtype)
 
@@ -259,9 +295,14 @@ def _pipeline_kernel(
             right = jnp.broadcast_to(jax.lax.slice(cur, (0, W - 1), (T, W)), (T, r))
             curp = jnp.concatenate([left, cur, right], axis=1)
         elif boundary == "reflect":
-            left = jax.lax.rev(jax.lax.slice(cur, (0, 1), (T, r + 1)), (1,))
-            right = jax.lax.rev(jax.lax.slice(cur, (0, W - r - 1), (T, W - 1)), (1,))
-            curp = jnp.concatenate([left, cur, right], axis=1)
+            # mirrored columns r..1 and W-2..W-r-1, one lane slice each
+            # (Mosaic has no lowering for a lane-axis reverse)
+            def col(c, _cur=cur, _T=T):
+                return jax.lax.slice(_cur, (0, c), (_T, c + 1))
+
+            left = [col(c) for c in range(r, 0, -1)]
+            right = [col(c) for c in range(W - 2, W - r - 2, -1)]
+            curp = jnp.concatenate([*left, cur, *right], axis=1)
         else:  # periodic
             left = jax.lax.slice(cur, (0, W - r), (T, W))
             right = jax.lax.slice(cur, (0, 0), (T, r))
@@ -339,22 +380,23 @@ def stencil2d_pipeline(
         raise ValueError("negative stage radius")
     H, W = x.shape
     R = sum(r for _, r in stages)
-    for _, r in stages:
-        if r and boundary == "reflect" and W < r + 1:
-            raise ValueError(f"reflect columns need W >= radius+1, got W={W}")
-        if r and boundary == "periodic" and W < r:
-            raise ValueError(f"periodic columns need W >= radius, got W={W}")
+    panel = fused_panel(
+        H, W, x.dtype, tuple(r for _, r in stages), boundary,
+        block_rows=block_rows, halo_resident=halo_resident,
+    )
+    if panel is None:
+        raise ValueError(
+            f"no fused stencil panel for {x.shape} (radius {R}, boundary "
+            f"{boundary!r}, block_rows={block_rows})"
+        )
+    br, rp, wrap_local = panel
     has_aux = aux is not None
     if has_aux and aux.shape != x.shape:
         raise ValueError(f"aux shape {aux.shape} != grid shape {x.shape}")
     has_row0 = row0 is not None
     h_glob = H if global_rows is None else int(global_rows)
 
-    # resident periodic halos (§10): the wrap rows were delivered by the
-    # ring exchange, so panel geometry and index maps use the clamped
-    # (non-wrapping) family; the kernel's periodic path needs no row masks.
     geo_boundary = "zero" if (halo_resident and boundary == "periodic") else boundary
-    br, rp, wrap_local = pick_panel(H, W, x.dtype, R, geo_boundary, block_rows)
     nb = cdiv(H, br)
     interpret = force_interpret() if interpret is None else interpret
 

@@ -67,7 +67,7 @@ def lower_cell(cfg, cell, mesh, *, accum_steps: int = 1):
     pshard = specs.param_shardings(cfg, mesh)
     params_abs = tf.abstract_params(cfg)
 
-    with meshlib.set_mesh_compat(mesh):
+    with jax.sharding.set_mesh(mesh):
         if cell.kind == "train":
             oshard = specs.opt_shardings(cfg, mesh)
             opt_abs = jax.eval_shape(adamw.init, params_abs)

@@ -10,60 +10,7 @@ from __future__ import annotations
 import os
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    jax 0.4.37 lacks ``jax.sharding.AxisType`` (it landed in 0.5.x); on
-    such builds the ``axis_types`` kwarg is omitted — every axis is Auto
-    by default there, so semantics are identical.  All mesh construction
-    in the repo (and the subprocess test harnesses) routes through this
-    shim instead of touching ``AxisType`` directly.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh_compat(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.sharding.set_mesh`` where present; on jax 0.4.37 the ``Mesh``
-    object is itself the context manager (the legacy physical-mesh
-    resource env), which is what explicit-sharding jits need there.
-    """
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, across jax versions
-    (0.4.37 ships it as ``jax.experimental.shard_map.shard_map`` with the
-    ``check_rep`` spelling of ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
-def axis_size_compat(axis_name) -> int:
-    """Static size of a named mapped axis, across jax versions
-    (``jax.lax.axis_size`` is absent on 0.4.37, where
-    ``jax.core.axis_frame(name)`` returns the size directly)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax import core as _core
-
-    return _core.axis_frame(axis_name)
+from jax.sharding import AxisType
 
 
 def ring_perm(n: int, *, reverse: bool = False) -> list[tuple[int, int]]:
@@ -103,13 +50,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     dry-run compiles against."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Degenerate mesh over whatever devices exist (smoke/e2e runs)."""
     n = len(jax.devices())
-    return make_mesh_compat((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axes_info(mesh) -> dict:

@@ -49,9 +49,11 @@ def main() -> None:
     import numpy as np
 
     from repro import configs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer as tf
     from repro.serve.engine import Engine, Request
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)
     params = tf.init_params(key, cfg)

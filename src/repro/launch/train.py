@@ -21,6 +21,7 @@ import numpy as np
 from repro import configs
 from repro.data.pipeline import DataConfig, Prefetcher, make_source
 from repro.launch import mesh as meshlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import specs
 from repro.models import transformer as tf
 from repro.optim import adamw
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch)
     if args.mesh == "host":
         mesh = meshlib.make_host_mesh()
@@ -56,7 +58,7 @@ def main() -> None:
     oc = adamw.OptConfig(lr=args.lr, total_steps=args.steps, warmup_steps=max(1, args.steps // 20))
 
     key = jax.random.PRNGKey(args.seed)
-    with meshlib.set_mesh_compat(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = init_sharded(cfg, key, mesh)
         opt_state = adamw.init(params)
         step_fn = jax.jit(

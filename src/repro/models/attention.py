@@ -30,6 +30,8 @@ from repro.utils.scanutil import maybe_scan
 Array = jax.Array
 
 NEG_INF = -1e30
+#: query / key tile of the fused flash kernel path
+KERNEL_BLOCK = 512
 
 
 def _use_flash_kernel() -> bool:
@@ -93,7 +95,7 @@ def flash_attention(
 
         return flash_k.flash_attention(
             q * (d ** -0.5), k, v, causal=causal, q_offset=q_offset,
-            block_q=min(512, q.shape[2]), block_k=min(512, skv),
+            block_q=min(KERNEL_BLOCK, q.shape[2]), block_k=min(KERNEL_BLOCK, skv),
             interpret=jax.default_backend() != "tpu",
         )
     qg = _group_q(q, hkv)  # (B, Hkv, G, Sq, D)
@@ -168,7 +170,9 @@ def flash_attention_blockwise(
     b, hq, sq, d = q.shape
     skv = k.shape[2]
     cq = min(q_chunk, sq)
-    ck = min(chunk, skv)
+    # the KV tile the monolithic call would use: the kernel's key block on
+    # the kernel path, the scan chunk otherwise
+    ck = min(KERNEL_BLOCK if _use_flash_kernel() else chunk, skv)
 
     def block(qc, kc, vc, off):
         return flash_attention(
@@ -180,8 +184,8 @@ def flash_attention_blockwise(
         hi = min(sq, lo + cq)
         if causal:
             # KV rows past the block's last query are fully masked; keep
-            # chunk boundaries aligned with the monolithic path so the
-            # accumulation order is identical.
+            # KV tile boundaries (and the tile itself) aligned with the
+            # monolithic path so the accumulation order is identical.
             kv_hi = min(skv, -(-(q_offset + hi) // ck) * ck)
         else:
             kv_hi = skv

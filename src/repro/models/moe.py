@@ -270,7 +270,6 @@ def moe_sort_ep(
     from jax.sharding import PartitionSpec as P
 
     from repro.core import dist_plan
-    from repro.launch.mesh import shard_map_compat
     from repro.sharding.partition import ep_param_specs
 
     mc = cfg.moe
@@ -319,8 +318,9 @@ def moe_sort_ep(
             y = y + mlp.ffn_only(pl_["shared"], cfg, h2)
         return xl + y, aux
 
-    y, aux = shard_map_compat(
-        f, mesh, in_specs=(pspecs, P(axis, None)), out_specs=(P(axis, None), P())
+    y, aux = jax.shard_map(
+        f, mesh=mesh, in_specs=(pspecs, P(axis, None)),
+        out_specs=(P(axis, None), P()), check_vma=False,
     )(p, x.reshape(t, d))
     return y.reshape(b, s, d), aux
 

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import axis_size_compat, shard_map_compat
-
 
 def _quant(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -42,7 +40,7 @@ def _dequant(q: jax.Array, scale: jax.Array) -> jax.Array:
 def ring_allreduce_int8(x: jax.Array, axis_name: str) -> jax.Array:
     """Mean-all-reduce of ``x`` over ``axis_name`` with int8 ring hops.
     Call inside shard_map.  x: flat (L,) with L % n == 0."""
-    n = axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     fwd = [(i, (i + 1) % n) for i in range(n)]
     chunks = x.reshape(n, -1).astype(jnp.float32)
@@ -85,11 +83,9 @@ def compressed_allreduce_mean(tree, mesh, *, axis: str = "data"):
         pad = (-flat.size) % n
         flat = jnp.pad(flat, (0, pad))
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             functools.partial(ring_allreduce_int8, axis_name=axis),
-            mesh,
-            P(),
-            P(),
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
         )
         red = fn(flat)
         return red[: leaf.size].reshape(leaf.shape).astype(leaf.dtype)

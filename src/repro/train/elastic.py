@@ -48,10 +48,10 @@ def plan_mesh_shape(n_devices: int, model_width: int, *, pods: int = 1):
 
 def plan_mesh(n_devices: int, model_width: int, *, pods: int = 1):
     """Build the mesh for :func:`plan_mesh_shape`'s chosen layout."""
-    from repro.launch.mesh import make_mesh_compat
-
     shape, axes = plan_mesh_shape(n_devices, model_width, pods=pods)
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def rescale_batch(global_batch: int, old_data: int, new_data: int) -> int:

@@ -18,16 +18,14 @@ from jax.sharding import PartitionSpec as P
 from repro.optim.compress import ring_allreduce_int8, wire_bytes
 import functools
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 # per-device distinct values; replicated layout, each shard sees its own copy
 vals = rng.standard_normal((8, 4096)).astype(np.float32)
 
-from repro.launch.mesh import shard_map_compat
-fn = shard_map_compat(
+fn = jax.shard_map(
     functools.partial(ring_allreduce_int8, axis_name="data"),
-    mesh, P("data"), P("data"))
+    mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
 x = jnp.asarray(vals.reshape(-1))  # (8*4096,) sharded over data -> each dev one row
 out = np.asarray(fn(x)).reshape(8, 4096)
 want = vals.mean(axis=0)

@@ -53,9 +53,10 @@ def rand(shape, dtype):
 
 
 def make_mesh(shape):
-    from repro.launch.mesh import make_mesh_compat
-
-    return make_mesh_compat(shape, ("a", "b")[: len(shape)])
+    return jax.make_mesh(
+        shape, ("a", "b")[: len(shape)],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+    )
 
 
 def jaxpr_counts(fn, *args) -> dict:
@@ -521,10 +522,11 @@ def test_blockwise_train_loss_matches_monolithic_on_mesh():
     SPMD sharding (chunks slice the sequence axis, which stays
     replicated)."""
     from repro import configs
-    from repro.launch.mesh import make_mesh_compat, set_mesh_compat
     from repro.models import transformer as tf
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     cfg = configs.get_config("qwen2-7b-smoke").with_(
         dtype="float32", n_layers=2
     )
@@ -537,7 +539,7 @@ def test_blockwise_train_loss_matches_monolithic_on_mesh():
     def lossg(c):
         return jax.value_and_grad(lambda p: tf.loss_fn(p, c, tok, lab))(params)
 
-    with set_mesh_compat(mesh):
+    with jax.sharding.set_mesh(mesh):
         l_mono, g_mono = lossg(cfg)
         l_bw, g_bw = lossg(
             cfg.with_(blockwise=True, blockwise_chunk=32,
@@ -566,6 +568,7 @@ def test_dist_suite_on_8_fake_devices():
     env = {
         **os.environ,
         **fake_device_env(8),
+        "JAX_PLATFORMS": "cpu",
         "REPRO_DIST_CHILD": "1",
         "PYTHONPATH": "src" + os.pathsep + os.environ.get("PYTHONPATH", ""),
     }

@@ -87,8 +87,8 @@ def test_flash_decode_jaxpr_kernel_counts():
     assert len(re.findall(r"\bpallas_call\b", str(full))) == 2
     g = hq // hkv
     mid_o = jnp.zeros((b * hkv, 2, g, d), jnp.float32)
-    mid_m = jnp.zeros((b * hkv, 2, g), jnp.float32)
-    mid_l = jnp.zeros((b * hkv, 2, g), jnp.float32)
+    mid_m = jnp.zeros((b * hkv, 2, g, 1), jnp.float32)
+    mid_l = jnp.zeros((b * hkv, 2, g, 1), jnp.float32)
     comb = jax.make_jaxpr(
         lambda o, m, l: flash.decode_combine(o, m, l, num_splits=2, interpret=True)
     )(mid_o, mid_m, mid_l)

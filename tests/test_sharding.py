@@ -104,8 +104,7 @@ from repro.models import transformer as tf
 from repro.optim import adamw
 
 cfg = configs.get_config("qwen2-7b-smoke").with_(n_layers=2)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = cfg.with_(attn_shard="head")  # 4 heads / 4-way model axis
 step = specs.make_step(cfg, configs.SHAPE_CELLS["train_4k"], mesh)
 params_abs = tf.abstract_params(cfg)
@@ -116,8 +115,7 @@ inputs = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32),
           "labels": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
 in_sh = {"tokens": NamedSharding(mesh, P("data", None)),
          "labels": NamedSharding(mesh, P("data", None))}
-from repro.launch.mesh import set_mesh_compat
-with set_mesh_compat(mesh):
+with jax.sharding.set_mesh(mesh):
     lowered = jax.jit(step, in_shardings=(pshard, oshard, in_sh),
                       out_shardings=(pshard, oshard, None)).lower(params_abs, opt_abs, inputs)
     compiled = lowered.compile()

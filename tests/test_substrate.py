@@ -126,3 +126,29 @@ def test_trainer_accum_equivalence():
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=2e-2, atol=2e-2
         )
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR points (and the code
+    then sets no directory of its own), else to <checkout>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        if env_dir is None:
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
