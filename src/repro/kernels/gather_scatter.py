@@ -141,15 +141,6 @@ def _pad_table(idx: jax.Array, rows: int) -> jax.Array:
     return jnp.full((rows,), -1, jnp.int32).at[:n].set(idx)
 
 
-def _row_dma(src_hbm, s, rows_vmem, r, sem):
-    """Copy one source row ``s`` (HBM) into scratch row ``r`` (VMEM)."""
-    cp = pltpu.make_async_copy(
-        src_hbm.at[pl.ds(s, 1), :], rows_vmem.at[pl.ds(r, 1), :], sem
-    )
-    cp.start()
-    cp.wait()
-
-
 def _wide(dtype):
     """The 32-bit type rows are rotated in (Mosaic rotates only 32-bit
     data; narrower types widen exactly and narrow back)."""
@@ -305,36 +296,41 @@ def gather_rows_blocked(
     )(idxp.reshape(nB, 1, br), x)
 
 
-def _gather_combine_kernel(back_ref, src_hbm, gates_ref, o_ref, rows, sem):
+def _gather_combine_kernel(back_ref, src_hbm, gates_ref, o_ref, rows, tiles, sem):
     """One grid step = one (bt, C) combined-output block.
 
-    Gathers the block's ``bt * k`` source rows into VMEM (sentinels
-    zero-filled), then performs the weighted combine entirely on-chip:
-    ``out[t] = sum_k gates[t, k] * rows[t, k]`` — the gathered (T*k, C)
-    intermediate never exists in HBM.
-    """
-    i = pl.program_id(0)
+    ``back_ref`` is this block's (bt * k,) slice of the flattened table in
+    SMEM.  The block's bt * k source rows land in the VMEM block ``rows``
+    one group of T table entries at a time (T = the dtype's sublane count),
+    each row fetched as the aligned tile that holds it and rotated into
+    place (:func:`_fetch_rows`; sentinels stay zero), so every store is
+    tile-aligned.  Then the weighted combine runs on-chip, as the same
+    expression as the oracle: ``out[t] = sum_k gates[t, k] * rows[t, k]``
+    — the gathered (T*k, C) intermediate never exists in HBM."""
     bt, C = o_ref.shape
     k = gates_ref.shape[1]
-    base = i * bt * k
+    T = tiles.shape[1]
+    n = bt * k
 
-    def body(j, carry):
-        s = back_ref[base + j]
+    def store(off, size):
+        srcs = [back_ref[off + r] for r in range(size)]
+        got = _fetch_rows(src_hbm, tiles, sem, srcs)[:size]
+        rows[pl.ds(off, size), :] = got.astype(rows.dtype)
 
-        @pl.when(s >= 0)
-        def _():
-            _row_dma(src_hbm, s, rows, j, sem)
+    gs = min(T, n)  # whole groups at tile-aligned offsets, then the rest
 
-        @pl.when(s < 0)
-        def _():
-            rows[pl.ds(j, 1), :] = jnp.zeros((1, C), o_ref.dtype)
-
+    def body(i, carry):
+        store(pl.multiple_of(i * gs, gs), gs)
         return carry
 
-    jax.lax.fori_loop(0, bt * k, body, 0)
+    jax.lax.fori_loop(0, n // gs, body, 0)
+    if n % gs:
+        store(n - n % gs, n % gs)
     v = rows[...].reshape(bt, k, C)
-    g = gates_ref[...].astype(o_ref.dtype)
-    o_ref[...] = (v * g[..., None]).sum(axis=1)
+    # the gates gain their lane axis in f32 (Mosaic cannot reshape a
+    # narrower gate block); both casts are exact, so g matches the oracle's
+    g = gates_ref[...].astype(jnp.float32)[..., None].astype(o_ref.dtype)
+    o_ref[...] = (v * g).sum(axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -371,21 +367,26 @@ def gather_combine_blocked(
     bt = max(1, min(block_t, T))
     nT = cdiv(T, bt)
     backp = _pad_table(back.reshape(-1), nT * bt * k)
+    sl = sublanes(src.dtype)
+    if n_src % sl:
+        src = jnp.pad(src, ((0, sl - n_src % sl), (0, 0)))
 
     interpret = force_interpret() if interpret is None else interpret
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nT,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((bt, k), lambda i, back_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, C), lambda i, back_ref: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((bt * k, C), src.dtype), pltpu.SemaphoreType.DMA],
-    )
     return pl.pallas_call(
         _gather_combine_kernel,
-        grid_spec=grid_spec,
+        grid=(nT,),
+        in_specs=[
+            pl.BlockSpec((None, None, bt * k), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((bt, k), lambda i: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((bt, C), lambda i: (i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((bt * k, C), src.dtype),
+            pltpu.VMEM((sl, sl, C), src.dtype),
+            pltpu.SemaphoreType.DMA((sl,)),
+        ],
         out_shape=jax.ShapeDtypeStruct((T, C), src.dtype),
         interpret=interpret,
-    )(backp, src, gates)
+    )(backp.reshape(nT, 1, bt * k), src, gates)

@@ -109,3 +109,10 @@ def test_masked_blocked_gather_compiles(one_chip, chip_dispatch):
 
     specs = [((65536, 3584), BF16), ((65536,), jnp.int32)]
     assert _kernels(gather, one_chip, *specs) == 1
+
+
+def test_moe_gather_combine_compiles(one_chip, chip_dispatch):
+    # mixtral-8x7b widths: d_model 4096, top-2 of 8 experts, 8192 tokens
+    # at capacity factor 1.25 -> 8 * 2560 expert rows
+    specs = [((20480, 4096), BF16), ((8192, 2), jnp.int32), ((8192, 2), F32)]
+    assert _kernels(ops.gather_combine, one_chip, *specs) == 1
