@@ -326,11 +326,12 @@ def _gather_combine_kernel(back_ref, src_hbm, gates_ref, o_ref, rows, tiles, sem
     jax.lax.fori_loop(0, n // gs, body, 0)
     if n % gs:
         store(n - n % gs, n % gs)
-    v = rows[...].reshape(bt, k, C)
-    # the gates gain their lane axis in f32 (Mosaic cannot reshape a
-    # narrower gate block); both casts are exact, so g matches the oracle's
-    g = gates_ref[...].astype(jnp.float32)[..., None].astype(o_ref.dtype)
-    o_ref[...] = (v * g).sum(axis=1)
+    # products and the k-sum in f32, rounded once: how XLA evaluates the
+    # oracle's narrow-dtype multiply + sum fusion (a product of two values
+    # of a narrower float type is exact in f32)
+    v = rows[...].reshape(bt, k, C).astype(jnp.float32)
+    g = gates_ref[...].astype(o_ref.dtype).astype(jnp.float32)[..., None]
+    o_ref[...] = (v * g).sum(axis=1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -349,10 +350,10 @@ def gather_combine_blocked(
     with ``back[t, k] < 0`` contributing zero.  ``src``: (n_src, C);
     ``back``: int (T, k); ``gates``: (T, k) float.  ONE `pallas_call`
     replaces the seed's gather -> reshape -> multiply -> sum chain, and
-    the (T*k, C) gathered intermediate never round-trips HBM.  The
-    per-``k`` accumulation order and dtype match the unfused chain
-    (products and sum in ``src.dtype``), so results are bit-identical to
-    the seed path.  Planned by :func:`repro.core.index_plan.plan_index_op`
+    the (T*k, C) gathered intermediate never round-trips HBM.  Products
+    and the per-``k`` sum run in f32 and round once to ``src.dtype``, as
+    XLA evaluates the unfused chain, so results are bit-identical to the
+    seed path.  Planned by :func:`repro.core.index_plan.plan_index_op`
     with ``semantics="gather_combine"``.
     """
     if src.ndim != 2 or back.ndim != 2 or gates.shape != back.shape:
