@@ -978,6 +978,7 @@ def decode_combine(
         out_specs=pl.BlockSpec((1, g, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, g, d), mid_o.dtype),
         interpret=interpret,
+        name="decode_combine",
     )(mid_o, mid_m, mid_l)
 
 
@@ -1065,6 +1066,7 @@ def flash_decode(
             jax.ShapeDtypeStruct((b * hkv, ns, g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(lens, q3, k3, v3)
     out = decode_combine(mid_o, mid_m, mid_l, num_splits=ns, interpret=interpret)
     return out.reshape(b, hq, 1, d).astype(q.dtype)

@@ -52,6 +52,7 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer as tf
     from repro.serve.engine import Engine, Request
+    from repro.utils import spans
 
     enable_compile_cache()
     cfg = configs.get_config(args.arch)
@@ -79,6 +80,24 @@ def main() -> None:
           f"({total_new/dt:.1f} tok/s) [mode={engine.mode} chunk={engine.chunk}]")
     for r in done[:3]:
         print(f"  req {r.rid}: first tokens {r.out[:8]}")
+    print(engine_totals(spans.spans()))
+
+
+def engine_totals(recorded) -> str:
+    """One line of the engine's totals from its spans: prompt rows over the
+    rows prefilled for them, and seconds spent sampling on the host and
+    unpacking admitted KV rows."""
+    rows = [a for name, _, _, a in recorded if name in ("engine.admit", "engine.chunk_wave")]
+    real = sum(a["rows_real"] for a in rows)
+    computed = sum(a["rows_computed"] for a in rows)
+
+    def seconds(name):
+        return sum(t1 - t0 for n, t0, t1, _ in recorded if n == name)
+
+    use = f"{100 * real / computed:.1f}%" if computed else "n/a"
+    return (f"engine: prefill rows {real}/{computed} used ({use}), "
+            f"{seconds('engine.sample'):.3f} s in engine.sample, "
+            f"{seconds('engine.write_ragged'):.3f} s in engine.write_ragged")
 
 
 if __name__ == "__main__":

@@ -18,6 +18,9 @@ The engine owns B fixed slots and runs three planned hot-path routes
   on kernel backends the decode attention is the split-KV
   `kernels.flash.flash_decode` two-stage reduce.
 
+Each admission wave, chunk wave, decode, KV unpack and host sample is a
+span of `repro.utils.spans` carrying the rows or slots it covered.
+
 Static shapes throughout: one compiled ragged prefill per packed width,
 one compiled chunk step, one compiled decode.  The seed's left-padded
 bucket prefill survives as ``prefill_mode="bucket"`` — the measured
@@ -38,6 +41,7 @@ import numpy as np
 from repro.core import index_plan as ip
 from repro.kernels import ops
 from repro.models import transformer as tf
+from repro.utils.spans import span
 
 Array = jax.Array
 
@@ -96,20 +100,24 @@ class Engine:
         self.live: list[Request | None] = [None] * batch_slots
         self.frontend = None
         self._finished: list[Request] = []  # done at admission, drained by step
-        self._decode = jax.jit(
-            lambda p, tok, cache, pos: tf.decode_step(p, cfg, tok, cache, pos)
-        )
-        self._prefill = jax.jit(lambda p, toks: tf.prefill(p, cfg, toks))
-        self._prefill_ragged = jax.jit(
-            lambda p, toks, seg, pos, last: tf.prefill_ragged(
-                p, cfg, toks, seg, pos, last
-            )
-        )
-        self._prefill_chunk = jax.jit(
-            lambda p, toks, cache, pos, active, last: tf.prefill_chunk(
-                p, cfg, toks, cache, pos, active, last
-            )
-        )
+
+        # named functions, so the programs are jit_decode, jit_prefill, ...
+        def decode(p, tok, cache, pos):
+            return tf.decode_step(p, cfg, tok, cache, pos)
+
+        def prefill(p, toks):
+            return tf.prefill(p, cfg, toks)
+
+        def prefill_ragged(p, toks, seg, pos, last):
+            return tf.prefill_ragged(p, cfg, toks, seg, pos, last)
+
+        def prefill_chunk(p, toks, cache, pos, active, last):
+            return tf.prefill_chunk(p, cfg, toks, cache, pos, active, last)
+
+        self._decode = jax.jit(decode)
+        self._prefill = jax.jit(prefill)
+        self._prefill_ragged = jax.jit(prefill_ragged)
+        self._prefill_chunk = jax.jit(prefill_chunk)
 
     # -- admission -----------------------------------------------------------
 
@@ -125,7 +133,11 @@ class Engine:
     def admit_batch(self, reqs: list[Request]) -> list[int]:
         """Admit up to ``len(free slots)`` requests in one wave; in ragged
         mode the whole wave shares ONE packed prefill.  Returns the chosen
-        slot per admitted request (prefix of ``reqs``)."""
+        slot per admitted request (prefix of ``reqs``).
+
+        The wave is the span ``engine.admit``: ``rows_real`` prompt tokens
+        prefilled in ``rows_computed`` rows (the packed width, or the
+        bucket pads)."""
         free = self.free_slots()
         reqs = reqs[: len(free)]
         if not reqs:
@@ -140,21 +152,27 @@ class Engine:
                 )
         slots = free[: len(reqs)]
         if self.mode == "ragged":
-            self._admit_ragged(reqs, slots)
+            heads = [
+                min(len(r.prompt), self.chunk) if self.chunk else len(r.prompt)
+                for r in reqs
+            ]
+            lay = ip.ragged_layout(tuple(heads), self.bucket)
+            with span("engine.admit", n=len(reqs), rows_real=sum(heads),
+                      rows_computed=lay.t_pad):
+                self._admit_ragged(reqs, slots, heads, lay)
         else:
-            for r, s in zip(reqs, slots):
-                self._admit_bucket(r, s)
+            pads = [self._bucket_pad(r) for r in reqs]
+            with span("engine.admit", n=len(reqs),
+                      rows_real=sum(len(r.prompt) for r in reqs),
+                      rows_computed=sum(pads)):
+                for r, s, pad in zip(reqs, slots, pads):
+                    self._admit_bucket(r, s, pad)
         return slots
 
-    def _admit_ragged(self, reqs: list[Request], slots: list[int]) -> None:
-        """Packed admission: prefill the head of every prompt (all of it,
-        or the first ``chunk`` tokens) in one ragged batch and gather the
-        packed KV rows into the slots."""
-        heads = [
-            min(len(r.prompt), self.chunk) if self.chunk else len(r.prompt)
-            for r in reqs
-        ]
-        lay = ip.ragged_layout(tuple(heads), self.bucket)
+    def _admit_ragged(self, reqs: list[Request], slots: list[int], heads, lay) -> None:
+        """Packed admission: prefill the head of every prompt (``heads``:
+        all of it, or the first ``chunk`` tokens) in one ragged batch laid
+        out as ``lay`` and gather the packed KV rows into the slots."""
         toks = np.zeros((1, lay.t_pad), np.int32)
         for j, r in enumerate(reqs):
             toks[0, lay.indptr[j] : lay.indptr[j] + heads[j]] = r.prompt[: heads[j]]
@@ -167,26 +185,33 @@ class Engine:
             jnp.asarray(lay.positions),
             jnp.asarray(last),
         )
-        self.cache = _write_ragged(self.cache, packed, slots, lay, self.s_max)
-        lg = np.asarray(logits)
-        for j, (r, s) in enumerate(zip(reqs, slots)):
-            r.slot = s
-            self.live[s] = r
-            self.pos[s] = heads[j]
-            self.off[s] = heads[j]
-            self.chunking[s] = heads[j] < len(r.prompt)
-            if not self.chunking[s]:
-                self._emit(s, int(np.argmax(lg[j])))
+        with span("engine.write_ragged", n=len(reqs)):
+            self.cache = _write_ragged(self.cache, packed, slots, lay, self.s_max)
+        done = sum(h == len(r.prompt) for h, r in zip(heads, reqs))
+        with span("engine.sample", n=done):
+            lg = np.asarray(logits)
+            for j, (r, s) in enumerate(zip(reqs, slots)):
+                r.slot = s
+                self.live[s] = r
+                self.pos[s] = heads[j]
+                self.off[s] = heads[j]
+                self.chunking[s] = heads[j] < len(r.prompt)
+                if not self.chunking[s]:
+                    self._emit(s, int(np.argmax(lg[j])))
 
-    def _admit_bucket(self, req: Request, slot: int) -> None:
-        """The seed route: one left-padded bucket prefill per request."""
-        s = len(req.prompt)
-        pad = -(-s // self.bucket) * self.bucket
+    def _bucket_pad(self, req: Request) -> int:
+        """The bucket width ``req``'s prompt is left-padded to."""
+        pad = -(-len(req.prompt) // self.bucket) * self.bucket
         if pad > self.s_max:
             raise ValueError(
                 f"request {req.rid}: prompt bucket ({pad}) exceeds s_max "
                 f"({self.s_max})"
             )
+        return pad
+
+    def _admit_bucket(self, req: Request, slot: int, pad: int) -> None:
+        """The seed route: one left-padded bucket prefill per request."""
+        s = len(req.prompt)
         toks = np.zeros((1, pad), np.int32)
         toks[0, pad - s :] = req.prompt  # left-pad into the bucket
         logits, cache1 = self._prefill(self.params, jnp.asarray(toks))
@@ -197,7 +222,8 @@ class Engine:
         self.pos[slot] = pad
         self.off[slot] = s
         self.chunking[slot] = False
-        self._emit(slot, int(np.argmax(np.asarray(logits)[0])))
+        with span("engine.sample", n=1):
+            self._emit(slot, int(np.argmax(np.asarray(logits)[0])))
 
     def _emit(self, slot: int, token: int) -> None:
         """Record one generated token for ``slot``; retire the request when
@@ -221,34 +247,34 @@ class Engine:
         if not slots:
             return
         c = self.chunk
-        toks = np.zeros((self.b, c), np.int32)
-        active = np.zeros((self.b,), bool)
-        last = np.zeros((self.b,), np.int32)
-        counts: dict[int, int] = {}
-        for i in slots:
-            r = self.live[i]
-            off = int(self.off[i])
-            n = min(c, len(r.prompt) - off)
-            toks[i, :n] = r.prompt[off : off + n]
-            active[i] = True
-            last[i] = n - 1
-            counts[i] = n
-        logits, self.cache = self._prefill_chunk(
-            self.params,
-            jnp.asarray(toks),
-            self.cache,
-            jnp.asarray(self.pos),
-            jnp.asarray(active),
-            jnp.asarray(last),
-        )
-        lg = np.asarray(logits)
-        for i in slots:
-            r = self.live[i]
-            self.off[i] += counts[i]
-            self.pos[i] += counts[i]
-            if int(self.off[i]) == len(r.prompt):
-                self.chunking[i] = False
-                self._emit(i, int(np.argmax(lg[i])))
+        counts = {i: min(c, len(self.live[i].prompt) - int(self.off[i])) for i in slots}
+        with span("engine.chunk_wave", slots=len(slots), rows_real=sum(counts.values()),
+                  rows_computed=self.b * c):
+            toks = np.zeros((self.b, c), np.int32)
+            active = np.zeros((self.b,), bool)
+            last = np.zeros((self.b,), np.int32)
+            for i in slots:
+                off, n = int(self.off[i]), counts[i]
+                toks[i, :n] = self.live[i].prompt[off : off + n]
+                active[i] = True
+                last[i] = n - 1
+            logits, self.cache = self._prefill_chunk(
+                self.params,
+                jnp.asarray(toks),
+                self.cache,
+                jnp.asarray(self.pos),
+                jnp.asarray(active),
+                jnp.asarray(last),
+            )
+            done = [i for i in slots if int(self.off[i]) + counts[i] == len(self.live[i].prompt)]
+            with span("engine.sample", n=len(done)):
+                lg = np.asarray(logits)
+                for i in slots:
+                    self.off[i] += counts[i]
+                    self.pos[i] += counts[i]
+                for i in done:
+                    self.chunking[i] = False
+                    self._emit(i, int(np.argmax(lg[i])))
 
     def step(self) -> list[Request]:
         """One engine step: a chunk wave for prefilling slots, then one
@@ -261,16 +287,19 @@ class Engine:
             if r is not None and not self.chunking[i]
         ]
         if decode_ix:
-            toks = np.zeros((self.b,), np.int32)
-            for i in decode_ix:
-                toks[i] = self.live[i].out[-1]
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(toks), self.cache, jnp.asarray(self.pos)
-            )
-            lg = np.asarray(logits)
-            for i in decode_ix:
-                self.pos[i] += 1
-                self._emit(i, int(np.argmax(lg[i])))
+            kv_rows = sum(min(int(self.pos[i]) + 1, self.s_max) for i in decode_ix)
+            with span("engine.decode", slots=len(decode_ix), kv_rows=kv_rows):
+                toks = np.zeros((self.b,), np.int32)
+                for i in decode_ix:
+                    toks[i] = self.live[i].out[-1]
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(toks), self.cache, jnp.asarray(self.pos)
+                )
+                with span("engine.sample", n=len(decode_ix)):
+                    lg = np.asarray(logits)
+                    for i in decode_ix:
+                        self.pos[i] += 1
+                        self._emit(i, int(np.argmax(lg[i])))
             finished.extend(self._finished)
             self._finished = []
         return finished

@@ -21,6 +21,7 @@ Layers covered, bottom-up:
 """
 
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -322,3 +323,107 @@ def test_serve_flags_tpu_gated(monkeypatch):
     # opt-in: without force, REPRO_SERVE_FLAGS gates the whole preset
     monkeypatch.delenv("REPRO_SERVE_FLAGS", raising=False)
     assert xla_flags.apply_serve_flags() is None
+
+
+# -- the engine's spans and counters ------------------------------------------
+
+
+def _engine_spans(name=None):
+    from repro.utils import spans
+
+    return [s for s in spans.spans() if name is None or s[0] == name]
+
+
+def test_engine_admit_counts_packed_rows(qwen):
+    from repro.utils import spans
+
+    cfg, params = qwen
+    engine = Engine(cfg, params, batch_slots=2, s_max=64, prompt_bucket=16)
+    spans.reset()
+    engine.admit_batch([Request(rid=0, prompt=np.ones(5, np.int32)),
+                        Request(rid=1, prompt=np.ones(9, np.int32))])
+    (admit,) = _engine_spans("engine.admit")
+    # 5 + 9 prompt rows packed into one 16-row bucket
+    assert admit[3] == {"n": 2, "rows_real": 14, "rows_computed": 16}
+    assert [s[3] for s in _engine_spans("engine.write_ragged")] == [{"n": 2}]
+    # the decode step attends rows [0, 5] and [0, 9] of the two rings
+    engine.step()
+    (decode,) = _engine_spans("engine.decode")
+    assert decode[3] == {"slots": 2, "kv_rows": 6 + 10}
+
+
+def test_engine_chunk_wave_counts_rows(qwen):
+    from repro.utils import spans
+
+    cfg, params = qwen
+    engine = Engine(cfg, params, batch_slots=2, s_max=64, prompt_bucket=16, chunk=8)
+    spans.reset()
+    engine.admit_batch([Request(rid=0, prompt=np.ones(20, np.int32), max_new=2)])
+    engine.step()
+    engine.step()
+    # the head of 8 rows in a 16-row bucket, then waves of 8 and 4 real rows
+    # each computed over both slots' 8-row chunks
+    assert [s[3] for s in _engine_spans("engine.admit")] == [
+        {"n": 1, "rows_real": 8, "rows_computed": 16}]
+    assert [s[3] for s in _engine_spans("engine.chunk_wave")] == [
+        {"slots": 1, "rows_real": 8, "rows_computed": 16},
+        {"slots": 1, "rows_real": 4, "rows_computed": 16}]
+    # the first decode follows the last wave in the same step: rows [0, 20]
+    assert [s[3] for s in _engine_spans("engine.decode")] == [{"slots": 1, "kv_rows": 21}]
+
+
+@pytest.mark.parametrize(
+    "mode,chunk", [("ragged", 8), ("bucket", None)], ids=["ragged_chunked", "bucket"],
+)
+def test_engine_one_sample_per_program_call(qwen, served, mode, chunk):
+    from repro.utils import spans
+
+    cfg, params = qwen
+    prompts, _ = served
+    engine = Engine(cfg, params, batch_slots=2, s_max=64, prompt_bucket=16,
+                    prefill_mode=mode, chunk=chunk)
+    calls = []
+    for attr in ("_decode", "_prefill", "_prefill_ragged", "_prefill_chunk"):
+        fn = getattr(engine, attr)
+
+        def counted(*args, _fn=fn, _attr=attr):
+            calls.append(_attr)
+            return _fn(*args)
+
+        setattr(engine, attr, counted)
+    spans.reset()
+    engine.run([Request(rid=i, prompt=p, max_new=5) for i, p in enumerate(prompts)])
+    samples = _engine_spans("engine.sample")
+    assert calls and len(samples) == len(calls)
+    # every sample lies inside the span of the program call it follows
+    parents = [s for s in _engine_spans() if s[0] != "engine.sample"
+               and s[0] != "engine.write_ragged"]
+    for _, t0, t1, _ in samples:
+        assert sum(p[1] <= t0 and t1 <= p[2] for p in parents) == 1
+    assert spans.dropped() == 0
+
+
+def test_engine_spans_fall_inside_the_callers_interval(qwen, served):
+    from repro.utils import spans
+
+    cfg, params = qwen
+    prompts, _ = served
+    engine = Engine(cfg, params, batch_slots=2, s_max=64, prompt_bucket=16, chunk=8)
+    spans.reset()
+    calls = []
+    pending = [Request(rid=i, prompt=p, max_new=4) for i, p in enumerate(prompts)]
+    while pending or any(r is not None for r in engine.live):
+        t0 = time.perf_counter()
+        if pending and engine.free_slots():
+            n = len(engine.free_slots())
+            engine.admit_batch(pending[:n])
+            pending = pending[n:]
+        else:
+            engine.step()
+        calls.append((t0, time.perf_counter()))
+    recorded = _engine_spans()
+    assert {s[0] for s in recorded} == {"engine.admit", "engine.write_ragged",
+                                        "engine.chunk_wave", "engine.decode",
+                                        "engine.sample"}
+    for name, s0, s1, _ in recorded:
+        assert sum(t0 <= s0 <= s1 <= t1 for t0, t1 in calls) == 1, name
