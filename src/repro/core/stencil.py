@@ -127,6 +127,7 @@ class StencilPlan:
     bytes_moved: int  # fused-path HBM traffic (reads incl. halo + 1 write)
     bytes_per_sweep_path: int  # traffic of n_stages separate sweeps
     roofline_s: float  # fused bytes / HBM bandwidth (one chip)
+    shift: str  # roll | slice: how the fused kernel takes its views ("" on the reference path)
     stages_exec: tuple = field(repr=False, hash=False, compare=False)
 
     def describe(self) -> str:
@@ -136,7 +137,8 @@ class StencilPlan:
             f"{self.mode}: shape={self.shape} stages={self.n_stages} "
             f"radius={self.total_radius} boundary={self.boundary} "
             f"panel=({self.block_rows}+2*{self.halo_block_rows} halo rows)x{self.grid} "
-            f"{self.bytes_moved/1e6:.2f} MB moved vs "
+            + (f"shift={self.shift} " if self.shift else "")
+            + f"{self.bytes_moved/1e6:.2f} MB moved vs "
             f"{self.bytes_per_sweep_path/1e6:.2f} MB per-sweep ({saving:.1f}x), "
             f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_GBPS} GB/s"
         )
@@ -174,13 +176,18 @@ def _build_plan(
     n = H * W
 
     br = rp = 0
-    mode = "reference"
+    mode, shift = "reference", ""
     if n > 0:
         panel = st_k.fused_panel(H, W, dtype_name, radii, boundary,
                                  block_rows=block_rows)
         if panel is not None:
             br, rp, _ = panel
             mode = "fused"
+            # the kernel is called with block_rows=br, and its route is
+            # fixed by the panel it derives from that
+            kernel_panel = st_k.fused_panel(H, W, dtype_name, radii, boundary,
+                                            block_rows=br)
+            shift = st_k.shift_route(W, dtype_name, kernel_panel, radii)
     if block_rows is not None and mode != "fused":
         raise ValueError("no fused panel for this block_rows override")
     grid = cdiv(H, br) if br else 0
@@ -213,6 +220,7 @@ def _build_plan(
         bytes_per_sweep_path=bytes_per_sweep,
         roofline_s=(bytes_fused if mode == "fused" else bytes_per_sweep)
         / (HBM_GBPS * 1e9),
+        shift=shift,
         stages_exec=stages_exec,
     )
 

@@ -8,9 +8,9 @@ stencil compiles to a specialized kernel.
 
 TPU version:
 * row-panel decomposition: each grid step owns a (block_rows, W) panel with
-  the full row width resident in VMEM — column halos are then free (they
-  are just lane shifts within the panel), which deletes the paper's
-  misaligned-apron problem instead of patching it with texture fetches.
+  the full row width resident in VMEM, so no column apron is ever loaded —
+  the paper's misaligned-apron problem is deleted instead of patched with
+  texture fetches.
 * the row halo is expressed by passing the input again with small
   halo-block specs above and below the owned panel (clamped index maps).
   The Pallas pipeline DMAs each as a lane-aligned tile — the overlap costs
@@ -18,12 +18,31 @@ TPU version:
   redundancy the paper reports, but every load stays aligned.
 * **temporal blocking** (`stencil2d_pipeline`): a program of k stages is
   applied entirely in VMEM.  The panel is loaded once with a halo of
-  ``sum(radius_i)`` rows; each stage consumes its radius from the halo
-  (shrink-and-mask) and the final stage's panel is the only store.  One
-  HBM round trip replaces k.
+  ``sum(radius_i)`` rows, every stage runs on it, and the final stage's
+  owned rows are the only store.  One HBM round trip replaces k.
+* **how a stage takes its shifted views** (:func:`shift_route`, fixed by
+  the shapes alone):
+
+  - *roll* — a row of whole lanes (``W % 128 == 0``), a 32-bit dtype and a
+    band of whole sublane tiles: the band keeps ONE fixed, tile-aligned
+    shape (the owned rows plus the halo blocks) through every stage, and
+    ``shift(dy, dx)`` is a sublane rotation then a lane rotation of it.
+    Rows the rotation wraps in are garbage but lie in the row apron, which
+    is never stored; lanes it wraps in are the periodic boundary, and for
+    zero / nearest / reflect a select on the lane index fixes them in the
+    first or last lane tile only.  Linear stages scale the band once per
+    distinct weight and rotate the scaled band.
+  - *slice* — everything else: each stage consumes its radius from the
+    band (shrink), re-pads its columns by concatenation and takes each
+    view as a static slice.  On the chip those slices are misaligned: a
+    one-lane pad shifts every vreg, and the shrinking band has an
+    unaligned row count at every stage.
 * the boundary-condition family ``zero | nearest | reflect | periodic`` is
   resolved per stage against *global* row indices (which also kills OOB
-  garbage in the final partial panel) plus a boundary-correct column pad.
+  garbage in the final partial panel) plus the boundary's column
+  extension.  On the roll path the row masking and the one-hot regather
+  run only on panels whose band leaves the domain: one branch per panel
+  picks between those stages (a loop) and the interior's (unrolled).
 * functors run at **trace time** — the exact analogue of the paper's
   compile-time C++ functor: any jnp expression over ``shift(dy, dx)`` views
   specializes the kernel with no interpretive overhead.
@@ -32,14 +51,18 @@ TPU version:
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import BOUNDARY_PAD_MODES
 from repro.kernels.tiling import (
+    LANES,
     VMEM_BYTES,
     cdiv,
     force_interpret,
@@ -60,7 +83,10 @@ def _linear_functor(offsets: tuple, weights: tuple) -> Callable:
 
     Memoizing on the (offsets, weights) table keeps the functor's identity
     stable across calls, so jit tracing caches hit instead of respecializing
-    the kernel for every invocation of the same stencil.
+    the kernel for every invocation of the same stencil.  The table rides
+    along as ``functor.taps``: the rotation path scales the band once per
+    distinct weight and rotates the scaled band (``w * roll(x) ==
+    roll(w * x)``, so the products and their order are unchanged).
     """
 
     def functor(shift, *_unused):
@@ -70,6 +96,7 @@ def _linear_functor(offsets: tuple, weights: tuple) -> Callable:
             acc = term if acc is None else acc + term
         return acc
 
+    functor.taps = tuple(zip(offsets, weights))
     return functor
 
 
@@ -189,9 +216,121 @@ def fused_panel(
         return None
 
 
+def shift_route(
+    W: int, dtype, panel: tuple[int, int, bool], radii: tuple[int, ...]
+) -> str:
+    """How the fused kernel takes a stage's ``shift(dy, dx)`` views for a
+    ``panel`` of :func:`fused_panel` — fixed by the shapes alone.
+
+    * ``"roll"`` — the band keeps one fixed shape through every stage and
+      each view is a sublane and a lane rotation of it.  Taken when the row
+      is whole lanes (``W % 128 == 0``), the dtype is 32-bit, the band and
+      its row apron are whole sublane tiles, and every stage radius is under
+      one lane tile (the column fix reads the first and last lane tiles).
+    * ``"slice"`` — everything else: each stage re-pads its columns and
+      slices the views out of a band that shrinks by the stage's radius.
+    """
+    br, rp, wrap_local = panel
+    apron = 0 if (wrap_local or not sum(radii)) else rp
+    sl = sublanes(dtype)
+    ok = (
+        W % LANES == 0
+        and jnp.dtype(dtype).itemsize == 4
+        and br % sl == 0
+        and apron % sl == 0
+        and max(radii, default=0) < LANES
+    )
+    return "roll" if ok else "slice"
+
+
+def _row_source(g, boundary, h_glob):
+    """The in-domain row that global row ``g`` re-extends from
+    (nearest / reflect; in-domain rows map to themselves)."""
+    if boundary == "reflect" and h_glob > 1:
+        p = 2 * h_glob - 2
+        m = g % p
+        return jnp.where(m < h_glob, m, p - m)
+    return jnp.clip(g, 0, h_glob - 1)  # nearest / clamp (and a 1-row reflect)
+
+
+def _regather(c, pos):
+    """Rows ``c[pos]`` of a band, 0 where ``pos`` falls outside it, as a
+    one-hot row-gather on the MXU at full f32 precision (a bf16 pass would
+    round the copied rows)."""
+    T = c.shape[0]
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (T, T), 1) == pos).astype(jnp.float32)
+    return jax.lax.dot_general(
+        sel,
+        c.astype(jnp.float32),
+        (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(c.dtype)
+
+
+def _col_view(a, dx, boundary, fill):
+    """``a[:, c + dx]`` with the boundary's column extension, as one lane
+    rotation of ``a``.  Lanes the rotation wraps in are right only for
+    periodic; otherwise they are fixed by a select on the lane index,
+    confined to the first (``dx < 0``) or last (``dx > 0``) lane tile."""
+    if dx == 0:
+        return a
+    W = a.shape[1]
+    out = pltpu.roll(a, (-dx) % W, 1)
+    if boundary == "periodic":
+        return out
+    k, left = abs(dx), dx < 0
+    edge = slice(0, LANES) if left else slice(W - LANES, W)
+    piece, fixed = a[:, edge], out[:, edge]
+    lane = jax.lax.broadcasted_iota(jnp.int32, piece.shape, 1)
+    outside = lane < k if left else lane >= LANES - k
+    if boundary == "zero":
+        fixed = jnp.where(outside, fill, fixed)
+    elif boundary in ("nearest", "clamp"):
+        end = piece[:, :1] if left else piece[:, LANES - 1:]
+        fixed = jnp.where(outside, jnp.broadcast_to(end, piece.shape), fixed)
+    else:  # reflect: lane c reads column k - c (left) / 2W - 2 - c - k (right)
+        for j in range(k):
+            c = j if left else LANES - 1 - j
+            s = (2 * c - k if left else 2 * c + k + 2) % LANES
+            fixed = jnp.where(lane == c, pltpu.roll(piece, s, 1) if s else piece, fixed)
+    if W == LANES:
+        return fixed
+    if left:
+        return jnp.concatenate([fixed, out[:, LANES:]], axis=1)
+    return jnp.concatenate([out[:, : W - LANES], fixed], axis=1)
+
+
+def _rolled_views(band, r, boundary):
+    """``view(dy, dx, w)`` = ``w * shift(dy, dx)`` of a fixed (T, W) band
+    (``w=None``: unscaled): a sublane rotation for the row shift, then
+    :func:`_col_view` for the column shift, each computed once.  Rows the
+    sublane rotation wraps in are garbage; they lie in the row apron, which
+    is never stored (every stage radius is paid for by the apron)."""
+    T = band.shape[0]
+    scaled, rows, views = {}, {}, {}
+
+    def view(dy, dx, w=None):
+        if max(abs(dy), abs(dx)) > r:
+            raise ValueError(f"shift ({dy},{dx}) exceeds stage radius {r}")
+        if (dy, dx, w) not in views:
+            if w not in scaled:
+                scaled[w] = band if w is None else w * band
+            b = scaled[w]
+            if (dy, w) not in rows:
+                rows[dy, w] = pltpu.roll(b, (-dy) % T, 0) if dy else b
+            # zero columns read as w * 0, as w times the reference's pad
+            dt = b.dtype
+            fill = np.zeros((), dt) * np.asarray(1 if w is None else w, dt)
+            views[dy, dx, w] = _col_view(rows[dy, w], dx, boundary, fill)
+        return views[dy, dx, w]
+
+    return view
+
+
 def _pipeline_kernel(
     stages, boundary, br, rp, H, W, R, has_aux, wrap_local, h_glob, has_row0,
-    *refs,
+    roll, *refs,
 ):
     i = pl.program_id(0)
     o_ref = refs[-1]
@@ -204,6 +343,119 @@ def _pipeline_kernel(
     # the TRUE grid edges, not the shard edges.  Single-device calls pass
     # no row0 operand and h_glob == H — identical arithmetic to before.
     row0v = refs[pos_ref][0, 0] if has_row0 else 0
+    if roll:
+        _roll_stages(
+            stages, boundary, br, rp, H, R, has_aux, wrap_local, h_glob,
+            has_row0, i, row0v, x_refs, a_refs, o_ref,
+        )
+    else:
+        _slice_stages(
+            stages, boundary, br, rp, H, W, R, has_aux, wrap_local, h_glob,
+            has_row0, i, row0v, x_refs, a_refs, o_ref,
+        )
+
+
+def _roll_stages(
+    stages, boundary, br, rp, H, R, has_aux, wrap_local, h_glob, has_row0,
+    i, row0v, x_refs, a_refs, o_ref,
+):
+    """Rotation path: one fixed, tile-aligned band of global rows
+    ``[i*br - P, (i+1)*br + P)`` (P = the ``rp``-row halo block, 0 for a
+    single wrapping panel) serves every stage; each stage's views are
+    rotations of it (:func:`_rolled_views`) and the owned rows
+    ``[P, P + br)`` are the only store.  Row masking and the one-hot
+    regather run only on panels whose band leaves the domain, in a branch
+    of their own."""
+    if wrap_local or R == 0:
+        # a single wrapping panel holds the whole grid, so a sublane
+        # rotation IS the periodic row extension (R may exceed H)
+        P, band = 0, (lambda rs: rs[0][...])
+    else:
+        P, band = rp, (lambda rs: jnp.concatenate([r[...] for r in rs], axis=0))
+    tile = band(x_refs)
+    atile = band(a_refs) if has_aux else None
+    T = tile.shape[0]
+    e0 = i * br - P  # local row of band row 0
+    g0 = e0 + row0v
+
+    # rows past a window's local array (has_row0) only feed the cropped
+    # apron, so only the global edges decide the branch
+    touches = (g0 < 0) | (g0 + T > h_glob)
+
+    def in_domain():
+        j = jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+        ok = (j + g0 >= 0) & (j + g0 < h_glob)
+        if has_row0:
+            # window mode: rows past the local array (final-partial-panel
+            # padding) can sit inside the global domain
+            ok = ok & (j + e0 >= 0) & (j + e0 < H)
+        return j, ok
+
+    def edge_rows(c, h):
+        # zero rows outside the domain or this stage's dependency cone
+        # [P - h, P + br + h), so no garbage reaches the MXU; nearest /
+        # reflect then rebuild the outside rows from in-domain rows
+        j, ok = in_domain()
+        ok = ok & (j >= P - h) & (j < P + br + h)
+        c = jnp.where(ok, c, jnp.zeros((), c.dtype))
+        if boundary == "zero":
+            return c
+        return _regather(c, _row_source(j + g0, boundary, h_glob) - g0)
+
+    def stage(functor, r, edge, a, t, h):
+        cur = edge_rows(t, h) if edge else t
+        view = _rolled_views(cur, r, boundary)
+        taps = getattr(functor, "taps", None)
+        if taps is None:
+            return functor(view, lambda: a) if has_aux else functor(view)
+        out = None
+        for (dy, dx), w in taps:
+            term = view(dy, dx, w)
+            out = term if out is None else out + term
+        return out
+
+    def run(edge):
+        # Interior stages are unrolled, so the scheduler overlaps them.  On
+        # the few edge panels a run of equal stages (repeat(k)) is one
+        # loop: unrolled, its regathers make the program several times
+        # larger, and the interior step then slows (PERF.md §6)
+        a = atile
+        if edge and has_aux:
+            # zero out-of-domain aux rows (partial-panel garbage may be NaN)
+            a = jnp.where(in_domain()[1], a, jnp.zeros((), a.dtype))
+        t, h = tile, R
+        for (functor, r), group in itertools.groupby(stages):
+            n = len(list(group))
+            step = functools.partial(stage, functor, r, edge, a)
+            loop = edge and n > 1
+            if loop:  # a loop carries the band, so a stage must keep its type
+                out = jax.eval_shape(lambda c, _s=step, _h=h: _s(c, _h), t)
+                loop = (out.shape, out.dtype) == (t.shape, t.dtype)
+            if loop:
+                t = jax.lax.fori_loop(
+                    0, n, lambda k, c, _s=step, _h=h, _r=r: _s(c, _h - k * _r), t
+                )
+            else:
+                for k in range(n):
+                    t = step(t, h - k * r)
+            h -= n * r
+        o_ref[...] = t[P:P + br].astype(o_ref.dtype)
+
+    if boundary == "periodic":
+        # the band holds the wrapped extension (mod index maps / resident
+        # rows / a single wrapping panel): no row is ever outside
+        run(False)
+        return
+    pl.when(touches)(lambda: run(True))
+    pl.when(jnp.logical_not(touches))(lambda: run(False))
+
+
+def _slice_stages(
+    stages, boundary, br, rp, H, W, R, has_aux, wrap_local, h_glob, has_row0,
+    i, row0v, x_refs, a_refs, o_ref,
+):
+    """Slice path: each stage shrinks the band by its radius, re-pads its
+    columns by concatenation, and takes each view as a static slice."""
 
     def band(rs):
         # assemble the halo'd panel: nominal global rows [i*br - R, (i+1)*br + R)
@@ -256,36 +508,17 @@ def _pipeline_kernel(
                 inside = inside & (eg >= 0) & (eg < H)
             cur = jnp.where(inside, tile, jnp.zeros((), tile.dtype))
             if boundary != "zero":
-                # re-extend the boundary from in-domain rows: a one-hot
-                # row-gather (pos may fall outside the band for rows deeper
-                # than this stage needs; those resolve to 0 and are shrunk
-                # away before they can matter).  Panels whose band lies
-                # fully in-domain skip it — the gather would be identity.
-                if boundary == "reflect" and h_glob > 1:
-                    p = 2 * h_glob - 2
-                    m = g % p
-                    src = jnp.where(m < h_glob, m, p - m)
-                else:  # nearest / clamp (and reflect on a 1-row grid)
-                    src = jnp.clip(g, 0, h_glob - 1)
-                pos = src - g0
-                cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-
-                def _regather(c, _pos=pos, _cols=cols):
-                    sel = (_cols == _pos).astype(jnp.float32)
-                    # full f32 precision: the one-hot product must copy
-                    # rows exactly (a bf16 MXU pass would round them)
-                    return jax.lax.dot_general(
-                        sel,
-                        c.astype(jnp.float32),
-                        (((1,), (0,)), ((), ())),
-                        precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32,
-                    ).astype(c.dtype)
-
+                # re-extend the boundary from in-domain rows (pos may fall
+                # outside the band for rows deeper than this stage needs;
+                # those resolve to 0 and are shrunk away before they can
+                # matter).  Panels whose band lies fully in-domain skip it —
+                # the gather would be identity.
+                pos = _row_source(g, boundary, h_glob) - g0
                 touches_edge = (g0 < 0) | (g0 + T > h_glob)
-                cur = jax.lax.cond(touches_edge, _regather, lambda c: c, cur)
-        # column halo: boundary-correct pad of r lanes per side (the full
-        # row is resident, so these are static lane shifts — free)
+                cur = jax.lax.cond(
+                    touches_edge, lambda c, _p=pos: _regather(c, _p), lambda c: c, cur
+                )
+        # column halo: boundary-correct pad of r lanes per side
         if r == 0:
             curp = cur
         elif boundary == "zero":
@@ -398,6 +631,7 @@ def stencil2d_pipeline(
 
     geo_boundary = "zero" if (halo_resident and boundary == "periodic") else boundary
     nb = cdiv(H, br)
+    roll = shift_route(W, x.dtype, panel, tuple(r for _, r in stages)) == "roll"
     interpret = force_interpret() if interpret is None else interpret
 
     def im_cur(i):
@@ -444,11 +678,19 @@ def stencil2d_pipeline(
             wrap_local,
             h_glob,
             has_row0,
+            roll,
         ),
         grid=(nb,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, W), im_cur),
         out_shape=jax.ShapeDtypeStruct((H, W), x.dtype),
+        # the roll path's two branches (edge, interior) each spill band-sized
+        # values to scoped VMEM of their own: about 16.8 MB with the
+        # benchmark's panel, over the compiler's 16 MiB default (v5e has
+        # 128 MiB of VMEM)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * VMEM_BYTES if roll else None
+        ),
         interpret=interpret,
     )(*operands)
 

@@ -74,11 +74,19 @@ def test_interlace_and_deinterlace_compile(one_chip, chip_dispatch):
     assert _kernels(lambda x: ops.deinterlace(x, n)[0], one_chip, ((n * L,), F32)) >= 1
 
 
-def test_fused_jacobi_compiles(one_chip, chip_dispatch):
+@pytest.mark.parametrize(
+    "shape",
+    [
+        (4096, 4096),
+        (65536, 7168),  # the benchmark's grid, the widest the fused plan takes
+    ],
+    ids=["4096x4096", "65536x7168"],
+)
+def test_fused_jacobi_compiles(one_chip, chip_dispatch, shape):
     prog = st.Stencil(((1, 0), (-1, 0), (0, 1), (0, -1)), (0.25,) * 4).repeat(8)
-    assert prog.compile((4096, 4096), F32, boundary="reflect").mode == "fused"
+    assert prog.compile(shape, F32, boundary="reflect").mode == "fused"
     fn = lambda x: prog(x, boundary="reflect")  # noqa: E731
-    assert _kernels(fn, one_chip, ((4096, 4096), F32)) == 1
+    assert _kernels(fn, one_chip, (shape, F32)) == 1
 
 
 def test_flash_forward_and_backward_compile(one_chip):

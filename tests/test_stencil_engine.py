@@ -228,6 +228,104 @@ def test_periodic_halo_deeper_than_grid():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4)
 
 
+# ---------------------------------------------------------------------------
+# shift routes: rotations of a fixed band vs slices of a shrinking band
+# ---------------------------------------------------------------------------
+
+_JACOBI = st.Stencil(((1, 0), (-1, 0), (0, 1), (0, -1)), (0.25,) * 4)
+# radius 2 with power-of-two weights: every product is exact, so a fused
+# multiply-add in the CPU backend cannot change a bit of the reference
+_CROSS2 = st.Stencil(
+    ((0, 0), (1, 0), (-1, 0), (0, 1), (0, -1), (2, 0), (-2, 0), (0, 2), (0, -2)),
+    (-0.5, 0.25, 0.25, 0.25, 0.25, -0.0625, -0.0625, -0.0625, -0.0625),
+)
+_PROGRAMS = {
+    "r1x8": _JACOBI.repeat(8),  # the benchmark's Jacobi: halo 8 = one tile
+    "r2x4": _CROSS2.repeat(4),
+    "r1x2": _JACOBI.repeat(2),  # halo 2 inside an 8-row halo block
+}
+
+
+def _route_cases():
+    cases = [
+        (b, prog, w, "plain")
+        for b in BOUNDARIES
+        for prog in _PROGRAMS
+        for w in (256, 200)  # lane multiple -> roll, else slice
+    ]
+    cases += [(b, "r1x8", 256, "aux") for b in BOUNDARIES]
+    cases += [(b, "r1x8", 256, "row0") for b in BOUNDARIES]
+    # negative weights on a zero grid: the zero boundary's columns must
+    # read w * 0 = -0.0, as the reference's padded zeros do
+    cases += [("zero", "neg", 256, "zeros")]
+    return cases
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("boundary,prog,width,extra", _route_cases())
+def test_fused_kernel_bit_exact_on_both_routes(boundary, prog, width, extra):
+    """The fused kernel equals ``ref.stencil_pipeline`` bit for bit on the
+    rotation path (lane-multiple width) and the slice path, over several
+    panels: H=150 leaves a partial final panel (periodic needs panels that
+    divide H, so it runs three whole ones), with an aux source term, and
+    in the global-row window mode of the halo exchange."""
+    program = _JACOBI.scale(-1.0).as_program() if prog == "neg" else _PROGRAMS[prog]
+    stages = program.compile((64, width), jnp.float32).stages_exec
+    H = 192 if boundary == "periodic" else 150
+    x = jnp.zeros((H, width)) if extra == "zeros" else rand((H, width))
+    radii = tuple(r for _, r in stages)
+    panel = st_k.fused_panel(H, width, jnp.float32, radii, boundary,
+                             halo_resident=extra == "row0")
+    assert panel is not None and panel[0] < H  # several panels
+    # periodic's halo block is the panel's smallest divisor >= R: 2 rows
+    # for r1x2, not a whole sublane tile, so that case stays on slices
+    roll = width % 128 == 0 and (boundary, prog) != ("periodic", "r1x2")
+    route = st_k.shift_route(width, jnp.float32, panel, radii)
+    assert route == ("roll" if roll else "slice")
+    if extra in ("plain", "zeros"):
+        got = st_k.stencil2d_pipeline(x, stages, boundary=boundary, interpret=True)
+        want = ref.stencil_pipeline(x, stages, boundary=boundary)
+    elif extra == "aux":
+        a = rand((H, width))
+        stages = ((_jacobi_src, 1),) * 8
+        got = st_k.stencil2d_pipeline(x, stages, boundary=boundary, aux=a,
+                                      interpret=True)
+        want = ref.stencil_pipeline(x, stages, boundary=boundary, aux=a)
+    else:
+        # a window of rows [-8, H - 8) of a grid of H - 20 rows: both grid
+        # edges fall inside it; rows whose cone leaves the window are cropped
+        row0, rows = -8, H - 20
+        got = st_k.stencil2d_pipeline(
+            x, stages, boundary=boundary, row0=jnp.int32(row0),
+            global_rows=rows, halo_resident=True, interpret=True,
+        )
+        want = ref.stencil_pipeline_window(
+            x, stages, boundary=boundary, row0=row0, global_rows=rows
+        )
+        R = sum(radii)
+        keep = slice(max(R, -row0), min(H - R, rows - row0))
+        got, want = got[keep], want[keep]
+    assert np.array_equal(_bits(got), _bits(want))
+
+
+def test_plan_reports_shift_route():
+    """The benchmark's program plans fused with a (64, 8) panel on the
+    rotation path; a width that is not whole lanes plans the slice path."""
+    prog = _JACOBI.repeat(8)
+    plan = prog.compile((65536, 7168), jnp.float32, boundary="reflect")
+    assert plan.mode == "fused"
+    assert (plan.block_rows, plan.halo_block_rows) == (64, 8)
+    assert plan.shift == "roll" and "shift=roll" in plan.describe()
+    plan = prog.compile((65536, 7000), jnp.float32, boundary="reflect")
+    assert plan.mode == "fused" and plan.shift == "slice"
+    assert "shift=slice" in plan.describe()
+    plan = _CROSS2.repeat(2).compile((64, 2), jnp.float32, boundary="reflect")
+    assert plan.mode == "reference" and "shift=" not in plan.describe()
+
+
 def test_single_sweep_boundary_family_dispatch(pallas_interpret):
     """ops.stencil2d now routes every boundary mode through the kernel."""
     s = st.fd_laplacian(1)
