@@ -46,7 +46,7 @@ def reference_greedy(cfg, params, prompt, max_new):
     out = [int(jnp.argmax(logits[0]))]
     pos = len(prompt)
     while len(out) < max_new and pos < S_MAX:
-        lg, ring = tf.decode_step(
+        lg, ring, _ = tf.decode_step(
             params, cfg, jnp.asarray([out[-1]], np.int32), ring, jnp.int32(pos)
         )
         pos += 1

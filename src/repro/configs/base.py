@@ -34,6 +34,13 @@ class MoEConfig:
     normalize_gates: bool = True
     dispatch: str = "dense"  # dense (GSPMD all-to-all) | sort (gather kernels)
     shard: str = "expert"  # expert (EP on model axis) | ffn (TP inside experts)
+    #: the experts this chip holds, ``(first, count)``, as the deployment
+    #: divides them over chips; ``None`` holds all of them
+    held: tuple[int, int] | None = None
+
+    def held_range(self) -> tuple[int, int]:
+        """``(first held expert, number held)``."""
+        return self.held if self.held is not None else (0, self.n_experts)
 
 
 @dataclass(frozen=True)
@@ -169,6 +176,7 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
             d_expert=64,
             n_shared=min(cfg.moe.n_shared, 1),
             capacity_factor=8.0,  # dropless at smoke scale: decode tests exact
+            normalize_gates=cfg.moe.normalize_gates,
             dispatch=cfg.moe.dispatch,
         )
         kw["d_ff_dense"] = 128 if cfg.d_ff_dense else None

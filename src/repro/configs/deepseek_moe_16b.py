@@ -1,6 +1,8 @@
-"""DeepSeekMoE-16B [arXiv:2401.06066; hf] — fine-grained MoE: 2 shared +
-64 routed experts, top-6, expert dim 1408; layer 0 is a dense FFN
-(d_ff_dense=10944 per the released checkpoint)."""
+"""DeepSeekMoE-16B [arXiv:2401.06066; hf deepseek-ai/deepseek-moe-16b-base
+``config.json``] — fine-grained MoE: 2 shared + 64 routed experts, top-6
+softmax routing whose gates are not renormalised (``norm_topk_prob:
+false``), expert dim 1408; layer 0 is a dense FFN (``intermediate_size``
+10944, ``first_k_dense_replace`` 1)."""
 
 from repro.configs.base import ModelConfig, MoEConfig
 
@@ -24,6 +26,7 @@ CONFIG = ModelConfig(
         d_expert=1408,
         n_shared=2,
         capacity_factor=1.25,
+        normalize_gates=False,
         dispatch="dense",
         shard="expert",  # 64 experts / 16-way model axis = 4 per shard
     ),

@@ -34,6 +34,7 @@ from repro.core.plan import RearrangePlan, plan_affine, plan_rearrange
 from repro.kernels import (
     copy as copy_k,
     gather_scatter as gs_k,
+    grouped_matmul as gm_k,
     interlace as il_k,
     permute3d as p3_k,
     ref,
@@ -190,6 +191,21 @@ def gather_combine(src: Array, back: Array, gates: Array) -> Array:
         )
         return apply_index_plan(src, back, plan, gates=gates)
     return ref.gather_combine(src, back, gates)
+
+
+def grouped_swiglu(
+    x: Array, w_gate: Array, w_up: Array, w_down: Array, tile_group: Array,
+    n_used: Array, *, tm: int,
+) -> Array:
+    """The MoE expert FFN over expert-sorted rows: each ``tm``-row tile of
+    ``x`` through the SwiGLU weights of its group (`kernels.grouped_matmul`;
+    ``tile_group`` and ``n_used`` as ``grouped_matmul.tile_groups`` gives
+    them).  ONE `pallas_call` where an expert's weights fit VMEM."""
+    d, f = w_gate.shape[1:]
+    if use_pallas() and gm_k.fits(d, f, tm, x.dtype):
+        return gm_k.grouped_swiglu(x, w_gate, w_up, w_down, tile_group, n_used,
+                                   tm=tm, interpret=_interpret())
+    return ref.grouped_swiglu(x, w_gate, w_up, w_down, tile_group, n_used, tm)
 
 
 def transpose2d_batched(x: Array, *, diagonal: bool = False) -> Array:

@@ -73,6 +73,32 @@ def gather_combine(src: Array, back: Array, gates: Array) -> Array:
     return (rows * gates.astype(rows.dtype)[..., None]).sum(axis=1)
 
 
+def grouped_swiglu(
+    x: Array, w_gate: Array, w_up: Array, w_down: Array, tile_group: Array,
+    n_used: Array, tm: int,
+) -> Array:
+    """Grouped SwiGLU oracle: each ``tm``-row tile of ``x`` through the
+    weights of its group ``tile_group[tile]`` only, one tile at a time, in
+    the kernel's precision (f32 products, the hidden rounded once to
+    ``x.dtype``): ``rows x 6·d·f`` FLOPs, as the kernel's grid, with one
+    expert's weights live at a time.  Rows of tiles past ``n_used`` are
+    zero.  Ground truth for `grouped_matmul.grouped_swiglu`."""
+    d = x.shape[1]
+
+    def tile(args):
+        xt, g, live = args
+        gate = jnp.dot(xt, w_gate[g], preferred_element_type=jnp.float32)
+        up = jnp.dot(xt, w_up[g], preferred_element_type=jnp.float32)
+        h = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+        y = jnp.dot(h, w_down[g], preferred_element_type=jnp.float32)
+        return jnp.where(live, y, 0.0).astype(x.dtype)
+
+    n_tiles = tile_group.shape[0]
+    live = jnp.arange(n_tiles) < n_used
+    out = jax.lax.map(tile, (x.reshape(n_tiles, tm, d), tile_group, live))
+    return out.reshape(x.shape)
+
+
 # ---------------------------------------------------------------------------
 # §III-B  permute / reorder
 # ---------------------------------------------------------------------------

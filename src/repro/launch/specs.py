@@ -159,16 +159,20 @@ def make_prefill_step(cfg: ModelConfig, mesh):
 
 
 def make_decode_step(cfg: ModelConfig, mesh):
-    """The jit-able single-token decode step for this config."""
+    """The jit-able single-token decode step for this config.  Its MoE
+    blocks take the mesh layer (``moe_layer="mesh"``), whose expert-axis
+    sharding the partitioner lowers to the expert all-to-all."""
     def decode_step(params, batch):
-        return tf.decode_step(
+        logits, cache, _ = tf.decode_step(
             params,
             cfg,
             batch["token"],
             batch["cache"],
             batch["pos"],
             frontend_src=batch.get("frontend_src"),
+            moe_layer="mesh",
         )
+        return logits, cache
 
     return decode_step
 

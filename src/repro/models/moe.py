@@ -14,6 +14,12 @@ Dispatch IS the paper's index-set rearrangement (§III-A / DESIGN.md §4):
   and the one the dry-run compiles.
 
 Auxiliary load-balancing loss (Switch-style) is returned alongside.
+
+Serving uses a third form, :func:`moe_held`: one chip's share of the
+experts (``MoEConfig.held``), routed over all of them and computed without
+dropping any assignment — the masked gather into held-expert-sorted rows,
+ONE grouped SwiGLU kernel over them (`kernels/grouped_matmul.py`), and the
+fused gather-combine back to token order.
 """
 
 from __future__ import annotations
@@ -21,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops
+from repro.kernels import grouped_matmul, ops
 from repro.models import common, mlp
 
 Array = jax.Array
@@ -32,13 +38,14 @@ def moe_init(key, cfg) -> dict:
     d = cfg.d_model
     f = mc.d_expert
     dt = cfg.np_dtype
+    n_held = mc.held_range()[1]  # the router keeps all n_experts outputs
     keys = jax.random.split(key, 6)
     p = {
         "norm": common.norm_init(cfg.norm, d),
         "w_router": common.truncated_normal_init(keys[0], (d, mc.n_experts), 1.0, jnp.float32),
-        "w_up": common.truncated_normal_init(keys[1], (mc.n_experts, d, f), 1.0, dt),
-        "w_gate": common.truncated_normal_init(keys[2], (mc.n_experts, d, f), 1.0, dt),
-        "w_down": common.truncated_normal_init(keys[3], (mc.n_experts, f, d), 1.0, dt),
+        "w_up": common.truncated_normal_init(keys[1], (n_held, d, f), 1.0, dt),
+        "w_gate": common.truncated_normal_init(keys[2], (n_held, d, f), 1.0, dt),
+        "w_down": common.truncated_normal_init(keys[3], (n_held, f, d), 1.0, dt),
     }
     if mc.n_shared:
         shared_cfg_ff = mc.d_expert * mc.n_shared
@@ -325,6 +332,97 @@ def moe_sort_ep(
     return y.reshape(b, s, d), aux
 
 
+#: the counts :func:`moe_held` returns: real (token, held expert)
+#: assignments, rows the grouped kernel computed, held experts given rows
+STATS = ("moe_rows_real", "moe_rows_computed", "moe_groups")
+
+
+def no_stats() -> Array:
+    """The counts of a layer that computes no experts."""
+    return jnp.zeros((len(STATS),), jnp.int32)
+
+
+def route_all(p: dict, mc, h2: Array) -> tuple[Array, Array]:
+    """The published router over all ``n_experts``: float32 logits at full
+    matmul precision, softmax, top-k over the full width, the gates
+    renormalised only where ``mc.normalize_gates`` says.  h2 (T, D) ->
+    (gates (T, k) f32, expert ids (T, k))."""
+    logits = jnp.dot(h2.astype(jnp.float32), p["w_router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), mc.top_k)
+    if mc.normalize_gates:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, idx
+
+
+def expert_tile_rows(tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows per tile of the grouped kernel: about what one expert receives
+    of ``tokens`` under even routing, as a power of two in [16, 128] (16 is
+    a bf16 sublane tile; 128 rows fill the MXU)."""
+    want = max(1, tokens * top_k // n_experts)
+    return min(128, max(16, 1 << (want - 1).bit_length()))
+
+
+def held_experts(
+    p: dict, cfg, h2: Array, gates: Array, local: Array, valid: Array
+) -> tuple[Array, Array]:
+    """The held experts' part of the layer, dropping no assignment.
+
+    ``local`` (T, k) is each assignment's expert id less the first held
+    one; assignments of rows not ``valid`` or to experts not held add
+    nothing.  The held assignments are ranked within their expert and laid
+    out expert by expert, each expert's run padded to whole tiles
+    (`grouped_matmul.tile_groups`): the buffer holds the worst case (every
+    row's assignments held) in static shape, and the kernel computes only
+    the tiles in use.  Returns ``(y (T, D) in h2's dtype, counts)`` with
+    the counts of :data:`STATS`."""
+    t, k = local.shape
+    n_held = p["w_up"].shape[0]
+    held = valid[:, None] & (local >= 0) & (local < n_held)
+    expert = jnp.where(held, local, n_held)  # n_held: a one-hot of zeros
+    onehot = jax.nn.one_hot(expert, n_held, dtype=jnp.int32).reshape(t * k, n_held)
+    sizes = onehot.sum(axis=0)
+    rank = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(-1).reshape(t, k)
+    tm = expert_tile_rows(t, k, cfg.moe.n_experts)
+    n_tiles = -(-t * min(k, n_held) // tm) + n_held  # sum over experts of ceil(n/tm)
+    starts, tile_group, n_used = grouped_matmul.tile_groups(sizes, tm, n_tiles)
+    slot = jnp.where(held, starts[jnp.minimum(expert, n_held - 1)] + rank, -1)
+    rows = n_tiles * tm
+    token_of = jnp.arange(t * k, dtype=jnp.int32) // k
+    src = jnp.full((rows,), -1, jnp.int32).at[jnp.where(held, slot, rows).reshape(-1)].set(
+        token_of, mode="drop")
+    xs = ops.gather_rows(h2, src, masked=True)  # (rows, D), sentinels zero
+    ye = ops.grouped_swiglu(xs, p["w_gate"], p["w_up"], p["w_down"], tile_group, n_used,
+                            tm=tm)
+    y = ops.gather_combine(ye, slot, gates)
+    counts = jnp.stack([sizes.sum(), n_used * tm, (sizes > 0).sum()]).astype(jnp.int32)
+    return y, counts
+
+
+def moe_held(p: dict, cfg, x: Array, valid: Array | None = None) -> tuple[Array, Array]:
+    """The serving MoE layer over this chip's experts (``cfg.moe.held``).
+
+    Every token is routed over all ``n_experts`` (:func:`route_all`); the
+    assignments that land on held experts are computed without dropping
+    any (:func:`held_experts`); the shared experts run for every token.
+    Rows where ``valid`` (x's leading shape) is False — padding — route
+    nowhere, so a real token's output does not depend on what else is in
+    the batch.  Returns ``(x + y, counts of STATS)``."""
+    mc = cfg.moe
+    if cfg.act != "swiglu":
+        raise ValueError(f"the held-expert layer computes SwiGLU experts, not {cfg.act!r}")
+    d = x.shape[-1]
+    h2 = common.apply_norm(cfg.norm, p["norm"], x).reshape(-1, d)
+    t = h2.shape[0]
+    valid = jnp.ones((t,), bool) if valid is None else valid.reshape(t)
+    gates, idx = route_all(p, mc, h2)
+    y, counts = held_experts(p, cfg, h2, gates, idx - mc.held_range()[0], valid)
+    y = y.astype(x.dtype)
+    if "shared" in p:
+        y = y + mlp.ffn_only(p["shared"], cfg, h2)
+    return x + y.reshape(x.shape), counts
+
+
 def moe_apply(p: dict, cfg, x: Array, *, capacity: int | None = None) -> tuple[Array, Array]:
     """Route to the configured dispatch strategy (``sort`` or ``dense``)."""
     if cfg.moe.dispatch == "sort":
@@ -335,10 +433,10 @@ def moe_apply(p: dict, cfg, x: Array, *, capacity: int | None = None) -> tuple[A
 def default_capacity(cfg, tokens: int) -> int:
     """Per-expert buffer size for ``tokens`` routed tokens: the GShard
     formula ``max(1, int(capacity_factor * tokens * top_k / n_experts))``.
-    The single definition every caller shares — the MoE layers here, the
-    benchmarks, and the ``repro.tune`` pre-warm CLI, whose whole point is
-    warming the exact plan keys (``n_out = n_experts * capacity``) that
-    serving will look up."""
+    The single definition every caller shares — the capacity-bucketed
+    layers here, the benchmarks, and the ``repro.tune`` pre-warm CLI,
+    whose whole point is warming the exact plan keys (``n_out = n_experts
+    * capacity``) those layers will look up."""
     mc = cfg.moe
     return max(1, int(mc.capacity_factor * tokens * mc.top_k / mc.n_experts))
 

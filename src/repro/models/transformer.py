@@ -318,9 +318,23 @@ def init_cache(cfg, batch: int, s_max: int) -> list:
     return caches
 
 
+def _serve_ffn(kind: str, p: dict, cfg, x: Array, valid: Array | None) -> tuple[Array, Array]:
+    """The feed-forward half of a serving block: the MoE layer over this
+    chip's held experts, through which only ``valid`` rows route
+    (`models.moe.moe_held`), or the dense MLP.  Returns ``(x, counts of
+    moe.STATS)``."""
+    if kind == "attn_moe":
+        return moe.moe_held(p["moe"], cfg, x, valid)
+    return mlp.mlp_apply(p["mlp"], cfg, x), moe.no_stats()
+
+
 def block_decode(
-    kind: str, p: dict, cfg, x1: Array, cache: dict, pos: Array, src: Array | None
-) -> tuple[Array, dict]:
+    kind: str, p: dict, cfg, x1: Array, cache: dict, pos: Array, src: Array | None,
+    valid: Array | None = None, moe_layer: str = "held",
+) -> tuple[Array, dict, Array]:
+    """One block of a decode step: ``(x1, new cache, counts of moe.STATS)``;
+    ``valid`` (B,) marks the slots whose rows may route to experts, and
+    ``moe_layer`` is as `decode_step` takes it."""
     if kind in ("attn", "attn_dense", "attn_moe", "dec"):
         akind = "swa" if cfg.attn_kind == "swa" else "full"
         sub = {k: cache[k] for k in ("k", "v")}
@@ -329,27 +343,29 @@ def block_decode(
         new.update(sub)
         if kind == "dec":
             x1 = attn.xattn_decode(p["xattn"], cfg, x1, cache["cross"])
-        if kind == "attn_moe":
+        if kind == "attn_moe" and moe_layer == "mesh":
             x1, _ = moe.moe_apply(
                 p["moe"], cfg, x1, capacity=moe.decode_capacity(cfg, x1.shape[0])
             )
-        else:
-            x1 = mlp.mlp_apply(p["mlp"], cfg, x1)
-        return x1, new
+            return x1, new, moe.no_stats()
+        x1, counts = _serve_ffn(kind, p, cfg, x1, valid)
+        return x1, new, counts
     if kind == "local":
         x1, new = attn.attn_decode(p["attn"], cfg, x1, cache, pos, kind="local")
-        return mlp.mlp_apply(p["mlp"], cfg, x1), new
-    if kind == "xattn":
+        x1 = mlp.mlp_apply(p["mlp"], cfg, x1)
+    elif kind == "xattn":
         x1 = attn.xattn_decode(p["xattn"], cfg, x1, cache)
-        return mlp.mlp_apply(p["mlp"], cfg, x1), cache
-    if kind == "mlstm":
-        return xlstm.mlstm_decode(p["cell"], cfg, x1, cache)
-    if kind == "slstm":
-        return xlstm.slstm_decode(p["cell"], cfg, x1, cache)
-    if kind == "rglru":
+        x1, new = mlp.mlp_apply(p["mlp"], cfg, x1), cache
+    elif kind == "mlstm":
+        x1, new = xlstm.mlstm_decode(p["cell"], cfg, x1, cache)
+    elif kind == "slstm":
+        x1, new = xlstm.slstm_decode(p["cell"], cfg, x1, cache)
+    elif kind == "rglru":
         x1, new = rglru.rglru_decode(p["cell"], cfg, x1, cache)
-        return mlp.mlp_apply(p["mlp"], cfg, x1), new
-    raise ValueError(kind)
+        x1 = mlp.mlp_apply(p["mlp"], cfg, x1)
+    else:
+        raise ValueError(kind)
+    return x1, new, moe.no_stats()
 
 
 def decode_step(
@@ -361,20 +377,32 @@ def decode_step(
     *,
     frontend_src: Array | None = None,
     batch_spec: P | None = None,
-) -> tuple[Array, list]:
-    """One serving step: next-token logits + updated cache.
+    valid: Array | None = None,
+    moe_layer: str = "held",
+) -> tuple[Array, list, Array]:
+    """One serving step: next-token logits, the updated cache, and the
+    counts of ``moe.STATS`` summed over the layers.
 
     ``pos`` may be a scalar (all slots at the same position, the seed
     path) or a (B,) per-slot vector — the continuous-batching engine's
     layout, threaded through to the attention ring writes and per-slot
-    length masks (DESIGN.md §12)."""
+    length masks (DESIGN.md §12).  ``valid`` (B,) marks the live slots
+    (default all): an idle slot's row routes to no expert.  ``moe_layer``
+    picks the MoE blocks' layer: ``"held"``, this chip's held experts,
+    dropless (`models.moe.moe_held`, what the engine serves); or
+    ``"mesh"``, the configured capacity dispatch at a lossless decode
+    capacity (`models.moe.moe_apply`), whose expert-axis sharding a mesh
+    lowering partitions (`launch.specs.make_decode_step`); it counts
+    nothing."""
+    if moe_layer not in ("held", "mesh"):
+        raise ValueError(f"moe_layer must be 'held' or 'mesh', got {moe_layer!r}")
     pos = jnp.asarray(pos)
     x = common.embed(params["embed"], token[:, None]).astype(cfg.np_dtype)
     if cfg.pos_embed == "sinusoidal":
         pv = pos[None] if pos.ndim == 0 else pos[:, None]
         x = x + common.sinusoidal_pos(pv, cfg.d_model).astype(cfg.np_dtype)
     src = frontend_src
-    new_caches = []
+    new_caches, total = [], moe.no_stats()
     for stage_params, stage_cache, (unit, count) in zip(
         params["stages"], cache, cfg.decoder_plan()
     ):
@@ -383,18 +411,21 @@ def decode_step(
             unit_params, unit_cache = xs
             if batch_spec is not None:
                 h = partition.constrain(h, batch_spec)
-            new_unit = {}
+            new_unit, counts = {}, moe.no_stats()
             for i, kind in enumerate(unit):
-                h, new_unit[f"b{i}"] = block_decode(
-                    kind, unit_params[f"b{i}"], cfg, h, unit_cache[f"b{i}"], pos, src
+                h, new_unit[f"b{i}"], c = block_decode(
+                    kind, unit_params[f"b{i}"], cfg, h, unit_cache[f"b{i}"], pos, src,
+                    valid, moe_layer,
                 )
-            return h, new_unit
+                counts = counts + c
+            return h, (new_unit, counts)
 
-        x, new_stage = maybe_scan(body, x, (stage_params, stage_cache))
+        x, (new_stage, counts) = maybe_scan(body, x, (stage_params, stage_cache))
         new_caches.append(new_stage)
+        total = total + counts.sum(axis=0)
     x = common.apply_norm(cfg.norm, params["final_norm"], x)
     logits = _logits_chunk(params, cfg, x)[:, 0]  # (B, V)
-    return logits, new_caches
+    return logits, new_caches, total
 
 
 def prefill(
@@ -508,17 +539,14 @@ def supports_ragged(cfg) -> bool:
 
 def _block_prefill_ragged(
     kind: str, p: dict, cfg, x: Array, positions: Array, seg_ids: Array
-) -> tuple[Array, dict]:
+) -> tuple[Array, dict, Array]:
     if kind not in ATTN_ONLY_KINDS:
         raise ValueError(f"ragged prefill supports attention blocks only, got {kind!r}")
     x, kv = attn.attn_prefill(
         p["attn"], cfg, x, kind="full", positions=positions, seg_ids=seg_ids
     )
-    if kind == "attn_moe":
-        x, _ = moe.moe_apply(p["moe"], cfg, x)
-    else:
-        x = mlp.mlp_apply(p["mlp"], cfg, x)
-    return x, kv
+    x, counts = _serve_ffn(kind, p, cfg, x, (seg_ids >= 0)[None])
+    return x, kv, counts
 
 
 def prefill_ragged(
@@ -530,52 +558,53 @@ def prefill_ragged(
     last_ix: Array,  # (n_seq,) packed index of each sequence's last token
     *,
     batch_spec: P | None = None,
-) -> tuple[Array, list]:
+) -> tuple[Array, list, Array]:
     """Packed ragged prefill: several prompts share ONE prefill batch in a
     ``qo_indptr``-style layout (`core.index_plan.ragged_layout`); attention
-    is segment-masked block-diagonal causal.  Returns (per-sequence
-    last-token logits (n_seq, V), packed caches whose KV rows sit in packed
-    order — the engine's ragged_rows IndexPlan gather unpacks them into the
-    decode slots)."""
+    is segment-masked block-diagonal causal and padding rows route to no
+    expert.  Returns (per-sequence last-token logits (n_seq, V), packed
+    caches whose KV rows sit in packed order — the engine's ragged_rows
+    IndexPlan gather unpacks them into the decode slots), and the counts
+    of ``moe.STATS`` summed over the layers."""
     x = common.embed(params["embed"], tokens).astype(cfg.np_dtype)
     if cfg.pos_embed == "sinusoidal":
         x = x + common.sinusoidal_pos(positions, cfg.d_model).astype(cfg.np_dtype)
-    caches = []
+    caches, total = [], moe.no_stats()
     for stage_params, (unit, count) in zip(params["stages"], cfg.decoder_plan()):
 
         def body(h, unit_params):
             if batch_spec is not None:
                 h = partition.constrain(h, batch_spec)
-            unit_cache = {}
+            unit_cache, counts = {}, moe.no_stats()
             for i, kind in enumerate(unit):
-                h, unit_cache[f"b{i}"] = _block_prefill_ragged(
+                h, unit_cache[f"b{i}"], c = _block_prefill_ragged(
                     kind, unit_params[f"b{i}"], cfg, h, positions, seg_ids
                 )
-            return h, unit_cache
+                counts = counts + c
+            return h, (unit_cache, counts)
 
         body = _maybe_remat(body, cfg)
-        x, stage_cache = maybe_scan(body, x, stage_params)
+        x, (stage_cache, counts) = maybe_scan(body, x, stage_params)
         caches.append(stage_cache)
+        total = total + counts.sum(axis=0)
     x = common.apply_norm(cfg.norm, params["final_norm"], x)
     h_last = x[0, last_ix]  # (n_seq, D)
     logits = _logits_chunk(params, cfg, h_last[:, None])[:, 0]
-    return logits, caches
+    return logits, caches, total
 
 
 def _block_prefill_chunk(
-    kind: str, p: dict, cfg, x: Array, cache: dict, pos: Array, active: Array
-) -> tuple[Array, dict]:
+    kind: str, p: dict, cfg, x: Array, cache: dict, pos: Array, active: Array,
+    valid: Array,
+) -> tuple[Array, dict, Array]:
     if kind not in ATTN_ONLY_KINDS:
         raise ValueError(f"chunked prefill supports attention blocks only, got {kind!r}")
     sub = {k: cache[k] for k in ("k", "v")}
     x, sub = attn.attn_prefill_chunk(p["attn"], cfg, x, sub, pos, active)
     new = dict(cache)
     new.update(sub)
-    if kind == "attn_moe":
-        x, _ = moe.moe_apply(p["moe"], cfg, x)
-    else:
-        x = mlp.mlp_apply(p["mlp"], cfg, x)
-    return x, new
+    x, counts = _serve_ffn(kind, p, cfg, x, valid)
+    return x, new, counts
 
 
 def prefill_chunk(
@@ -588,18 +617,21 @@ def prefill_chunk(
     last_ix: Array,  # (B,) index of each slot's last real token in the chunk
     *,
     batch_spec: P | None = None,
-) -> tuple[Array, list]:
+) -> tuple[Array, list, Array]:
     """Advance chunked prefill by one C-token chunk per active slot,
     writing KV rows at ``[pos, pos+C)`` directly into the engine cache
-    (inactive slots' caches pass through untouched).  Returns (logits at
-    each slot's ``last_ix`` chunk row, updated cache) — the logits matter
-    only for slots whose prompt ends inside this chunk."""
+    (inactive slots' caches pass through untouched).  Rows of inactive
+    slots and rows past ``last_ix`` route to no expert.  Returns (logits
+    at each slot's ``last_ix`` chunk row, updated cache) — the logits
+    matter only for slots whose prompt ends inside this chunk — and the
+    counts of ``moe.STATS`` summed over the layers."""
     pos = jnp.asarray(pos)
     x = common.embed(params["embed"], tokens).astype(cfg.np_dtype)
     if cfg.pos_embed == "sinusoidal":
         positions = pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
         x = x + common.sinusoidal_pos(positions, cfg.d_model).astype(cfg.np_dtype)
-    new_caches = []
+    valid = active[:, None] & (jnp.arange(tokens.shape[1])[None, :] <= last_ix[:, None])
+    new_caches, total = [], moe.no_stats()
     for stage_params, stage_cache, (unit, count) in zip(
         params["stages"], cache, cfg.decoder_plan()
     ):
@@ -608,17 +640,19 @@ def prefill_chunk(
             unit_params, unit_cache = xs
             if batch_spec is not None:
                 h = partition.constrain(h, batch_spec)
-            new_unit = {}
+            new_unit, counts = {}, moe.no_stats()
             for i, kind in enumerate(unit):
-                h, new_unit[f"b{i}"] = _block_prefill_chunk(
+                h, new_unit[f"b{i}"], c = _block_prefill_chunk(
                     kind, unit_params[f"b{i}"], cfg, h, unit_cache[f"b{i}"],
-                    pos, active,
+                    pos, active, valid,
                 )
-            return h, new_unit
+                counts = counts + c
+            return h, (new_unit, counts)
 
-        x, new_stage = maybe_scan(body, x, (stage_params, stage_cache))
+        x, (new_stage, counts) = maybe_scan(body, x, (stage_params, stage_cache))
         new_caches.append(new_stage)
+        total = total + counts.sum(axis=0)
     x = common.apply_norm(cfg.norm, params["final_norm"], x)
     h_last = jnp.take_along_axis(x, last_ix[:, None, None], axis=1)  # (B,1,D)
     logits = _logits_chunk(params, cfg, h_last)[:, 0]
-    return logits, new_caches
+    return logits, new_caches, total

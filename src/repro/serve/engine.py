@@ -19,7 +19,11 @@ The engine owns B fixed slots and runs three planned hot-path routes
   `kernels.flash.flash_decode` two-stage reduce.
 
 Each admission wave, chunk wave, decode, KV unpack and host sample is a
-span of `repro.utils.spans` carrying the rows or slots it covered.
+span of `repro.utils.spans` carrying the rows or slots it covered.  For a
+model with MoE layers, the admission, chunk-wave and decode programs also
+return the counts of `models.moe.STATS` summed over the layers; the
+engine pulls them with the logits and records them on the
+``engine.admit``, ``engine.chunk_wave`` and ``engine.decode`` spans.
 
 Static shapes throughout: one compiled ragged prefill per packed width,
 one compiled chunk step, one compiled decode.  The seed's left-padded
@@ -40,6 +44,7 @@ import numpy as np
 
 from repro.core import index_plan as ip
 from repro.kernels import ops
+from repro.models import moe
 from repro.models import transformer as tf
 from repro.utils.spans import span
 
@@ -102,8 +107,8 @@ class Engine:
         self._finished: list[Request] = []  # done at admission, drained by step
 
         # named functions, so the programs are jit_decode, jit_prefill, ...
-        def decode(p, tok, cache, pos):
-            return tf.decode_step(p, cfg, tok, cache, pos)
+        def decode(p, tok, cache, pos):  # tok < 0: an idle slot
+            return tf.decode_step(p, cfg, jnp.maximum(tok, 0), cache, pos, valid=tok >= 0)
 
         def prefill(p, toks):
             return tf.prefill(p, cfg, toks)
@@ -158,8 +163,8 @@ class Engine:
             ]
             lay = ip.ragged_layout(tuple(heads), self.bucket)
             with span("engine.admit", n=len(reqs), rows_real=sum(heads),
-                      rows_computed=lay.t_pad):
-                self._admit_ragged(reqs, slots, heads, lay)
+                      rows_computed=lay.t_pad) as sp:
+                self._admit_ragged(reqs, slots, heads, lay, sp)
         else:
             pads = [self._bucket_pad(r) for r in reqs]
             with span("engine.admit", n=len(reqs),
@@ -169,16 +174,17 @@ class Engine:
                     self._admit_bucket(r, s, pad)
         return slots
 
-    def _admit_ragged(self, reqs: list[Request], slots: list[int], heads, lay) -> None:
+    def _admit_ragged(self, reqs: list[Request], slots: list[int], heads, lay, sp) -> None:
         """Packed admission: prefill the head of every prompt (``heads``:
         all of it, or the first ``chunk`` tokens) in one ragged batch laid
-        out as ``lay`` and gather the packed KV rows into the slots."""
+        out as ``lay`` and gather the packed KV rows into the slots; the
+        MoE counts land on the wave's span ``sp``."""
         toks = np.zeros((1, lay.t_pad), np.int32)
         for j, r in enumerate(reqs):
             toks[0, lay.indptr[j] : lay.indptr[j] + heads[j]] = r.prompt[: heads[j]]
         last = np.zeros((self.b,), np.int32)  # padded to B: stable jit shape
         last[: len(reqs)] = lay.last_ix
-        logits, packed = self._prefill_ragged(
+        logits, packed, counts = self._prefill_ragged(
             self.params,
             jnp.asarray(toks),
             jnp.asarray(lay.seg_ids),
@@ -189,7 +195,7 @@ class Engine:
             self.cache = _write_ragged(self.cache, packed, slots, lay, self.s_max)
         done = sum(h == len(r.prompt) for h, r in zip(heads, reqs))
         with span("engine.sample", n=done):
-            lg = np.asarray(logits)
+            lg = self._pull(logits, counts, sp)
             for j, (r, s) in enumerate(zip(reqs, slots)):
                 r.slot = s
                 self.live[s] = r
@@ -225,6 +231,15 @@ class Engine:
         with span("engine.sample", n=1):
             self._emit(slot, int(np.argmax(np.asarray(logits)[0])))
 
+    def _pull(self, logits, counts, sp) -> np.ndarray:
+        """The logits on the host; for a model with MoE layers the counts
+        come in the same transfer and are recorded on the span ``sp``."""
+        if self.cfg.moe is None:
+            return np.asarray(logits)
+        lg, counts = jax.device_get((logits, counts))
+        sp.attrs.update(zip(moe.STATS, map(int, counts)))
+        return lg
+
     def _emit(self, slot: int, token: int) -> None:
         """Record one generated token for ``slot``; retire the request when
         it hits ``max_new`` or its ring is full."""
@@ -249,7 +264,7 @@ class Engine:
         c = self.chunk
         counts = {i: min(c, len(self.live[i].prompt) - int(self.off[i])) for i in slots}
         with span("engine.chunk_wave", slots=len(slots), rows_real=sum(counts.values()),
-                  rows_computed=self.b * c):
+                  rows_computed=self.b * c) as sp:
             toks = np.zeros((self.b, c), np.int32)
             active = np.zeros((self.b,), bool)
             last = np.zeros((self.b,), np.int32)
@@ -258,7 +273,7 @@ class Engine:
                 toks[i, :n] = self.live[i].prompt[off : off + n]
                 active[i] = True
                 last[i] = n - 1
-            logits, self.cache = self._prefill_chunk(
+            logits, self.cache, moe_counts = self._prefill_chunk(
                 self.params,
                 jnp.asarray(toks),
                 self.cache,
@@ -268,7 +283,7 @@ class Engine:
             )
             done = [i for i in slots if int(self.off[i]) + counts[i] == len(self.live[i].prompt)]
             with span("engine.sample", n=len(done)):
-                lg = np.asarray(logits)
+                lg = self._pull(logits, moe_counts, sp)
                 for i in slots:
                     self.off[i] += counts[i]
                     self.pos[i] += counts[i]
@@ -288,15 +303,15 @@ class Engine:
         ]
         if decode_ix:
             kv_rows = sum(min(int(self.pos[i]) + 1, self.s_max) for i in decode_ix)
-            with span("engine.decode", slots=len(decode_ix), kv_rows=kv_rows):
-                toks = np.zeros((self.b,), np.int32)
+            with span("engine.decode", slots=len(decode_ix), kv_rows=kv_rows) as sp:
+                toks = np.full((self.b,), -1, np.int32)  # -1: an idle slot
                 for i in decode_ix:
                     toks[i] = self.live[i].out[-1]
-                logits, self.cache = self._decode(
+                logits, self.cache, counts = self._decode(
                     self.params, jnp.asarray(toks), self.cache, jnp.asarray(self.pos)
                 )
                 with span("engine.sample", n=len(decode_ix)):
-                    lg = np.asarray(logits)
+                    lg = self._pull(logits, counts, sp)
                     for i in decode_ix:
                         self.pos[i] += 1
                         self._emit(i, int(np.argmax(lg[i])))

@@ -12,14 +12,18 @@ the worker given this file may do so.
 """
 
 import os
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.core import stencil as st
-from repro.kernels import flash, ops
+from repro.kernels import flash, grouped_matmul, ops
+from repro.models import moe
+from repro.models import transformer as tf
 
 
 @pytest.fixture(scope="module")
@@ -124,3 +128,86 @@ def test_moe_gather_combine_compiles(one_chip, chip_dispatch):
     # at capacity factor 1.25 -> 8 * 2560 expert rows
     specs = [((20480, 4096), BF16), ((8192, 2), jnp.int32), ((8192, 2), F32)]
     assert _kernels(ops.gather_combine, one_chip, *specs) == 1
+
+
+# deepseek-moe-16b widths: d_model 2048, 16 query and 16 KV heads of 128,
+# experts of width 1408, top-6 of 64 routed experts, 8 of them held here
+DS_D, DS_F, DS_HELD = 2048, 1408, 8
+
+
+def test_flash_decode_compiles_for_mha(one_chip):
+    def dec(q, k, v, lens):
+        return flash.flash_decode(q, k, v, lengths=lens, interpret=False)
+
+    specs = [((16, 16, 1, D), BF16), ((16, 16, S, D), BF16), ((16, 16, S, D), BF16),
+             ((16,), jnp.int32)]
+    assert _kernels(dec, one_chip, *specs) == 2
+
+
+@pytest.mark.parametrize("tokens", [16, 8192], ids=["decode", "chunk-wave"])
+def test_moe_dispatch_and_combine_compile_at_deepseek_width(one_chip, chip_dispatch, tokens):
+    tm = moe.expert_tile_rows(tokens, 6, 64)
+    rows = (-(-tokens * 6 // tm) + DS_HELD) * tm
+
+    def dispatch_combine(x, src, back, gates):
+        return ops.gather_combine(ops.gather_rows(x, src, masked=True), back, gates)
+
+    specs = [((tokens, DS_D), BF16), ((rows,), jnp.int32), ((tokens, 6), jnp.int32),
+             ((tokens, 6), F32)]
+    assert _kernels(dispatch_combine, one_chip, *specs) == 2
+
+
+def test_grouped_swiglu_compiles_at_deepseek_width(one_chip):
+    tm, n_tiles = 128, 392  # a chunk wave of 16 x 512 rows, top-6, 8 held
+
+    def gmm(x, wg, wu, wd, tile_group, n_used):
+        return grouped_matmul.grouped_swiglu(x, wg, wu, wd, tile_group, n_used, tm=tm,
+                                             interpret=False)
+
+    specs = [((n_tiles * tm, DS_D), BF16), ((DS_HELD, DS_D, DS_F), BF16),
+             ((DS_HELD, DS_D, DS_F), BF16), ((DS_HELD, DS_F, DS_D), BF16),
+             ((n_tiles,), jnp.int32), ((), jnp.int32)]
+    assert _kernels(gmm, one_chip, *specs) == 1
+
+
+@pytest.fixture(scope="module")
+def deepseek_stage(one_chip):
+    """The benchmark cell's program: deepseek-moe-16b's first pipeline
+    stage (the dense layer and six MoE layers) holding experts 0-7, 16
+    slots of a 4096-row ring, as shapes on the described chip."""
+    cfg = configs.get_config("deepseek-moe-16b")
+    cfg = cfg.with_(n_layers=7, explicit_plan=((("attn_dense",), 1), (("attn_moe",), 6)),
+                    moe=replace(cfg.moe, held=(0, DS_HELD)))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                           sharding=one_chip), tree)
+
+    params = on_chip(tf.abstract_params(cfg))
+    cache = on_chip(jax.eval_shape(lambda: tf.init_cache(cfg, 16, S)))
+    return cfg, params, cache
+
+
+def test_deepseek_decode_and_chunk_wave_compile(one_chip, chip_dispatch, deepseek_stage):
+    cfg, params, cache = deepseek_stage
+    b, c = 16, 512
+
+    def decode(p, tok, kv, pos):
+        return tf.decode_step(p, cfg, jnp.maximum(tok, 0), kv, pos, valid=tok >= 0)
+
+    def chunk_wave(p, toks, kv, pos, active, last):
+        return tf.prefill_chunk(p, cfg, toks, kv, pos, active, last)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    programs = {
+        "decode": jax.jit(decode).lower(params, i32(b), cache, i32(b)),
+        "chunk wave": jax.jit(chunk_wave).lower(
+            params, i32(b, c), cache, i32(b), jax.ShapeDtypeStruct((b,), bool, sharding=one_chip),
+            i32(b)),
+    }
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        for kernel in ("grouped_swiglu", "gather_rows_blocked", "gather_combine_blocked"):
+            assert f"%{kernel}" in text, (name, kernel)

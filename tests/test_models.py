@@ -86,7 +86,7 @@ def test_decode_matches_forward(arch):
     src = None
     if not cfg.encoder_layers and cfg.n_frontend_tokens:
         src = frontend.astype(cfg.np_dtype)
-    logits_d, _ = tf.decode_step(
+    logits_d, _, _ = tf.decode_step(
         params, cfg, toks[:, S], cache, jnp.int32(S), frontend_src=src
     )
     err = float(jnp.max(jnp.abs(logits_d - logits_full)))

@@ -155,7 +155,7 @@ def test_prefill_ragged_pack_unpack_oracle(qwen):
     for j, p in enumerate(prompts):
         toks[0, lay.indptr[j] : lay.indptr[j] + len(p)] = p
     last = np.asarray(lay.last_ix, np.int32)
-    logits, packed = tf.prefill_ragged(
+    logits, packed, _ = tf.prefill_ragged(
         params, cfg, jnp.asarray(toks), jnp.asarray(lay.seg_ids),
         jnp.asarray(lay.positions), jnp.asarray(last),
     )
@@ -190,12 +190,12 @@ def test_decode_step_per_slot_positions(qwen):
         cache = _write_slot(cache, c1, j, s_max)
     toks = jnp.asarray([3, 7], jnp.int32)
     pos = jnp.asarray([len(p) for p in prompts], jnp.int32)
-    logits, _ = tf.decode_step(params, cfg, toks, cache, pos)
+    logits, _, _ = tf.decode_step(params, cfg, toks, cache, pos)
     for j, p in enumerate(prompts):
         ring1 = _write_slot(tf.init_cache(cfg, 1, s_max),
                             tf.prefill(params, cfg, jnp.asarray(p)[None])[1],
                             0, s_max)
-        ref, _ = tf.decode_step(
+        ref, _, _ = tf.decode_step(
             params, cfg, toks[j : j + 1], ring1, jnp.int32(len(p))
         )
         assert jnp.allclose(
@@ -214,7 +214,7 @@ def _reference_greedy(cfg, params, prompt, max_new, s_max):
     out = [int(jnp.argmax(logits[0]))]
     pos = len(prompt)
     while len(out) < max_new and pos < s_max:
-        lg, ring = tf.decode_step(
+        lg, ring, _ = tf.decode_step(
             params, cfg, jnp.asarray([out[-1]], np.int32), ring, jnp.int32(pos)
         )
         pos += 1
