@@ -41,7 +41,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import cdiv, force_interpret, plan_copy_tiles, sublanes
+from repro.kernels.tiling import (
+    cdiv,
+    force_interpret,
+    hbm_tile_rows,
+    plan_copy_tiles,
+    sublanes,
+)
 
 # ---------------------------------------------------------------------------
 # row-wise kernels (seed generation; benchmark baseline)
@@ -149,49 +155,56 @@ def _wide(dtype):
 
 def _fetch_rows(x_hbm, tiles, sem, srcs):
     """Rows ``x[srcs[r]]`` at rows ``r`` of a (T, C) 32-bit value, zero
-    where ``srcs[r] < 0`` (``len(srcs) <= T``).
+    where ``srcs[r] < 0`` (``len(srcs) <= T``; T = the dtype's sublane
+    count, so the group is one aligned store).
 
-    A DMA addresses only whole (T, C) row tiles of a tiled HBM array (T =
-    the dtype's sublane count), so each row is fetched as the aligned tile
-    that holds it — all copies in flight at once — and rotated along the
-    sublanes so its row lands on row ``r`` (rotations take 32-bit data;
-    narrower dtypes widen exactly)."""
-    T, C = tiles.shape[1], tiles.shape[2]
+    A DMA addresses only whole (H, C) HBM tiles of the source (H =
+    :func:`hbm_tile_rows`: 8 rows for 32-bit and 16-bit dtypes, not the
+    16-row VMEM tile of bf16), so each row is fetched as the H-row tile
+    that holds it — all copies in flight at once — and rotated within its
+    H sublanes so that its row lands on row ``r % H`` (rotations take
+    32-bit data; narrower dtypes widen exactly).  It is selected into slab
+    ``r // H`` of the group: two 8-row slabs for bf16, one for f32."""
+    T, H, C = tiles.shape
     wide = _wide(tiles.dtype)
     copies = []
     for r, s in enumerate(srcs):
-        t0 = pl.multiple_of(jnp.maximum(s, 0) // T * T, T)
-        cp = pltpu.make_async_copy(x_hbm.at[pl.ds(t0, T), :], tiles.at[r], sem.at[r])
+        t0 = pl.multiple_of(jnp.maximum(s, 0) // H * H, H)
+        cp = pltpu.make_async_copy(x_hbm.at[pl.ds(t0, H), :], tiles.at[r], sem.at[r])
         pl.when(s >= 0)(cp.start)
         copies.append(cp)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (T, C), 0)
-    acc = jnp.zeros((T, C), wide)
+    # output row of each element: the T-row group as T / H slabs of H rows
+    slab = (T // H, H, C)
+    row = (jax.lax.broadcasted_iota(jnp.int32, slab, 0) * H
+           + jax.lax.broadcasted_iota(jnp.int32, slab, 1))
+    acc = jnp.zeros(slab, wide)
     for r, (s, cp) in enumerate(zip(srcs, copies)):
         pl.when(s >= 0)(cp.wait)
-        rolled = pltpu.roll(tiles[r].astype(wide), (r - s % T + T) % T, 0)
-        acc = jnp.where((sub == r) & (s >= 0), rolled, acc)
-    return acc
+        rolled = pltpu.roll(tiles[r].astype(wide), (r - s % H + H) % H, 0)
+        acc = jnp.where((row == r) & (s >= 0), rolled, acc)
+    return acc.reshape(T, C)
 
 
 def _gather_block_kernel(use_run: bool, idx_ref, x_hbm, o_ref, tiles, window, sem):
     """One grid step = one (br, C) output block.
 
     ``idx_ref`` is this block's (br,) slice of the index table in SMEM (a
-    whole table can outgrow SMEM).  A DMA can only address whole (T, C)
-    row tiles of a tiled array (T = the dtype's sublane count), so source
-    rows are fetched as aligned tiles and rotated into place in VMEM:
+    whole table can outgrow SMEM).  A DMA can only address whole HBM row
+    tiles of the source, so source rows are fetched as aligned tiles and
+    rotated into place in VMEM; stores go in groups of T rows (T = the
+    dtype's sublane count), so each is one aligned store:
 
     * run path — when the block's br indices are a contiguous run, ONE
-      copy fetches the aligned (br + T)-row window around it and one
+      copy fetches the T-aligned (br + T)-row window around it and one
       sublane rotation aligns the run (``use_run`` is static: False when
       br is not whole tiles or the window does not fit the source);
-    * row path — otherwise, per group of T output rows, the T source
-      tiles are fetched concurrently and each is rotated so its row lands
-      on its output row (:func:`_fetch_rows`).  Negative (sentinel) rows
-      fetch nothing and stay zero, so each group is one aligned store.
+    * row path — otherwise, per group of T output rows, the T source rows
+      are fetched concurrently, each as the 8-row HBM tile that holds it,
+      and rotated so that it lands on its output row (:func:`_fetch_rows`).
+      Negative (sentinel) rows fetch nothing and stay zero.
     """
     br, C = o_ref.shape
-    T = tiles.shape[1]
+    T = tiles.shape[0]
     start = idx_ref[0]
     wide = _wide(o_ref.dtype)
 
@@ -247,9 +260,10 @@ def gather_rows_blocked(
     step moves ``block_r`` full-width rows off the HBM-resident source.
     Contiguous index runs collapse to one block copy (run detection), and
     sentinel rows are zero-filled in-kernel — no caller-side sentinel-row
-    concatenates.  Rows move as whole sublane tiles (see
+    concatenates.  Rows move as whole HBM tiles (see
     :func:`_gather_block_kernel`); a source whose row count is not whole
-    tiles is zero-padded to whole tiles first.  Planned by
+    sublane tiles (the run path's window alignment) is zero-padded to
+    whole sublane tiles first.  Planned by
     :func:`repro.core.index_plan.plan_index_op`.
 
     This one kernel carries three plan semantics: masked ``gather``,
@@ -287,7 +301,7 @@ def gather_rows_blocked(
         ],
         out_specs=pl.BlockSpec((br, C), lambda i: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((T, T, C), x.dtype),
+            pltpu.VMEM((T, hbm_tile_rows(x.dtype), C), x.dtype),
             pltpu.VMEM((span if use_run else T, C), x.dtype),
             pltpu.SemaphoreType.DMA((T,)),
         ],
@@ -302,14 +316,14 @@ def _gather_combine_kernel(back_ref, src_hbm, gates_ref, o_ref, rows, tiles, sem
     ``back_ref`` is this block's (bt * k,) slice of the flattened table in
     SMEM.  The block's bt * k source rows land in the VMEM block ``rows``
     one group of T table entries at a time (T = the dtype's sublane count),
-    each row fetched as the aligned tile that holds it and rotated into
+    each row fetched as the 8-row HBM tile that holds it and rotated into
     place (:func:`_fetch_rows`; sentinels stay zero), so every store is
     tile-aligned.  Then the weighted combine runs on-chip, as the same
     expression as the oracle: ``out[t] = sum_k gates[t, k] * rows[t, k]``
     — the gathered (T*k, C) intermediate never exists in HBM."""
     bt, C = o_ref.shape
     k = gates_ref.shape[1]
-    T = tiles.shape[1]
+    T = tiles.shape[0]
     n = bt * k
 
     def store(off, size):
@@ -353,8 +367,11 @@ def gather_combine_blocked(
     the (T*k, C) gathered intermediate never round-trips HBM.  Products
     and the per-``k`` sum run in f32 and round once to ``src.dtype``, as
     XLA evaluates the unfused chain, so results are bit-identical to the
-    seed path.  Planned by :func:`repro.core.index_plan.plan_index_op`
-    with ``semantics="gather_combine"``.
+    seed path.  Source rows move as whole 8-row HBM tiles (see
+    :func:`_gather_combine_kernel`); a source whose row count is not whole
+    HBM tiles is zero-padded to whole tiles first.  Planned by
+    :func:`repro.core.index_plan.plan_index_op` with
+    ``semantics="gather_combine"``.
     """
     if src.ndim != 2 or back.ndim != 2 or gates.shape != back.shape:
         raise ValueError(
@@ -368,9 +385,9 @@ def gather_combine_blocked(
     bt = max(1, min(block_t, T))
     nT = cdiv(T, bt)
     backp = _pad_table(back.reshape(-1), nT * bt * k)
-    sl = sublanes(src.dtype)
-    if n_src % sl:
-        src = jnp.pad(src, ((0, sl - n_src % sl), (0, 0)))
+    sl, h = sublanes(src.dtype), hbm_tile_rows(src.dtype)
+    if n_src % h:  # whole fetch tiles: the only alignment the row fetch needs
+        src = jnp.pad(src, ((0, h - n_src % h), (0, 0)))
 
     interpret = force_interpret() if interpret is None else interpret
     return pl.pallas_call(
@@ -385,7 +402,7 @@ def gather_combine_blocked(
         out_specs=pl.BlockSpec((bt, C), lambda i: (i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bt * k, C), src.dtype),
-            pltpu.VMEM((sl, sl, C), src.dtype),
+            pltpu.VMEM((sl, h, C), src.dtype),
             pltpu.SemaphoreType.DMA((sl,)),
         ],
         out_shape=jax.ShapeDtypeStruct((T, C), src.dtype),

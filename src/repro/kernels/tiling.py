@@ -34,6 +34,16 @@ def sublanes(dtype) -> int:
     return {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
 
 
+def hbm_tile_rows(dtype) -> int:
+    """Rows of the tile a 2-D ``dtype`` array has in HBM: the least a DMA
+    can fetch of it.  XLA lays 32-bit and 16-bit arrays out in 8-row tiles
+    (bf16 as ``T(8,128)(2,1)``: 8 rows of packed pairs, not the 16-row
+    VMEM tile of :func:`sublanes`), and Mosaic refuses a DMA slice whose
+    rows are not whole tiles ("Slice shape along dimension 0 must be
+    aligned to tiling (8)").  1-byte types keep their sublane tile."""
+    return 8 if jnp.dtype(dtype).itemsize >= 2 else sublanes(dtype)
+
+
 def round_up(x: int, m: int) -> int:
     """Round ``x`` up to the next multiple of ``m``."""
     return -(-x // m) * m

@@ -284,6 +284,15 @@ def test_copy_tiles_shrink_stays_sublane_aligned():
         assert tp.block_r == rows or tp.block_r >= sl
 
 
+def test_hbm_tile_rows_is_eight_for_32_and_16_bit():
+    # the row gather's fetch height: XLA's 8-row HBM tile, not bf16's
+    # 16-row VMEM tile
+    assert tiling.hbm_tile_rows(jnp.float32) == 8
+    assert tiling.hbm_tile_rows(jnp.bfloat16) == 8
+    assert tiling.hbm_tile_rows(jnp.int32) == 8
+    assert tiling.sublanes(jnp.bfloat16) == 16
+
+
 # ---------------------------------------------------------------------------
 # the ops the planner unlocks (kernels in interpret mode)
 # ---------------------------------------------------------------------------

@@ -123,6 +123,22 @@ def test_masked_blocked_gather_compiles(one_chip, chip_dispatch):
     assert _kernels(gather, one_chip, *specs) == 1
 
 
+@pytest.mark.parametrize(
+    "n_src,c,dtype",
+    [
+        (65536, 3584, F32),  # the rearrange cell's shape in f32: 8-row fetch, 8-row group
+        (24, 3584, BF16),  # whole 8-row HBM tiles, not whole 16-row VMEM tiles
+    ],
+    ids=["f32-65536x3584", "bf16-24x3584"],
+)
+def test_masked_blocked_gather_compiles_per_dtype(one_chip, chip_dispatch, n_src, c, dtype):
+    def gather(x, idx):
+        return ops.gather_rows(x, idx, masked=True)
+
+    specs = [((n_src, c), dtype), ((n_src,), jnp.int32)]
+    assert _kernels(gather, one_chip, *specs) == 1
+
+
 def test_moe_gather_combine_compiles(one_chip, chip_dispatch):
     # mixtral-8x7b widths: d_model 4096, top-2 of 8 experts, 8192 tokens
     # at capacity factor 1.25 -> 8 * 2560 expert rows

@@ -85,6 +85,21 @@ def test_plan_cache_returns_identical_object():
 # blocked gather: oracle equivalence
 # ---------------------------------------------------------------------------
 
+# bf16 rows travel as 8-row HBM tiles into 16-row output groups: the
+# table sends rows from both 8-row halves of 16-row source spans to both
+# halves of each 16-row output group, with duplicates and sentinels, and
+# ends in a partial group and the source's last rows.
+TILE_HALVES_IDX = [
+    0, 15, 8, 7, -1, 16, 23, 9, 9, 1, -1, 22, 17, 6, 14, 2,
+    23, 8, 0, 0, 12, -1, 5, 19, 10, 3, 21, 11, 4, -1, 13, 18,
+    7, 16, 20,
+]
+
+
+def tile_halves_table(n_src):
+    return np.asarray(TILE_HALVES_IDX + [n_src - 1, n_src - 8, n_src - 9], np.int32)
+
+
 GATHER_CASES = [
     # (n_src, C, idx builder) — sentinels, duplicates, runs, ragged sizes
     (64, 128, lambda n: RNG.integers(0, n, 64)),
@@ -94,6 +109,9 @@ GATHER_CASES = [
     (200, 64, lambda n: np.arange(n)),  # pure contiguous run (fast path)
     (200, 64, lambda n: np.arange(5, 133)),  # misaligned run
     (8, 128, lambda n: RNG.integers(-1, n, 300)),  # n_out >> n_src
+    (24, 256, tile_halves_table),  # whole 8-row, not 16-row, tiles
+    (37, 256, tile_halves_table),  # neither
+    (64, 256, tile_halves_table),
 ]
 
 
@@ -197,6 +215,16 @@ def test_gather_combine_matches_oracle(n_src, c, t, k, dtype, pallas_interpret):
     src = rand((n_src, c), dtype)
     back = jnp.asarray(RNG.integers(-1, n_src, (t, k)), jnp.int32)
     gates = jnp.asarray(RNG.standard_normal((t, k)), jnp.float32)
+    got = jax.jit(ops.gather_combine)(src, back, gates)
+    want = jax.jit(ref.gather_combine)(src, back, gates)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n_src", [24, 37, 64])
+def test_bf16_gather_combine_across_tile_halves(n_src, pallas_interpret):
+    src = rand((n_src, 256), jnp.bfloat16)
+    back = jnp.asarray(tile_halves_table(n_src).reshape(19, 2))
+    gates = jnp.asarray(RNG.standard_normal((19, 2)), jnp.float32)
     got = jax.jit(ops.gather_combine)(src, back, gates)
     want = jax.jit(ref.gather_combine)(src, back, gates)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
