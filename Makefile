@@ -5,16 +5,6 @@
 #   make docs-check      public-API docstring lint (tools/check_docstrings.py)
 #   make test-interpret  kernel/engine suites with every op forced through
 #                        the Pallas interpreter (REPRO_PALLAS_INTERPRET=1)
-#   make bench           benchmark harness; writes BENCH_rearrange.json
-#                        (+ BENCH_stencil.json / BENCH_moe.json / BENCH_dist.json)
-#   make bench-smoke     the same harness on tiny deterministic shapes
-#                        (no JSON written — committed numbers stay intact)
-#   make bench-check     benchmark-regression gate (tools/check_bench.py):
-#                        structure + measured-path ratios of the committed
-#                        BENCH_*.json, plus a fresh smoke replay
-#   make bench-moe       MoE dispatch suite only; writes BENCH_moe.json
-#   make bench-dist      mesh-aware suite only (8 forced host devices in a
-#                        subprocess); writes BENCH_dist.json
 #   make test-dist       distributed plan-engine suite directly on 8 forced
 #                        host devices (the tier-1 run covers the same thing
 #                        through a subprocess launcher test)
@@ -30,12 +20,14 @@
 # kernel suites opt into interpret mode per-test via the pallas_interpret
 # fixture.  `test-interpret` covers the force-everything configuration on
 # the suites designed for it.
+#
+# The benchmark is not a make target: it runs on a TPU chip, one cell a
+# process (`python3 bench/run.py --workload <cell> --seed <n> --seconds 51`,
+# cells in BENCHMARK.json).
 
 PYTHONPATH := src
 
-.PHONY: test test-interpret test-dist test-serve test-train bench bench-smoke \
-	bench-check bench-moe bench-dist bench-serve bench-train lint check \
-	docs-check
+.PHONY: test test-interpret test-dist test-serve test-train lint check docs-check
 
 docs-check:
 	python tools/check_docstrings.py
@@ -48,24 +40,6 @@ test-interpret:
 		tests/test_kernels.py tests/test_plan_engine.py tests/test_substrate.py \
 		tests/test_properties.py tests/test_stencil_engine.py
 
-bench:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run
-
-bench-smoke:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --smoke
-
-bench-check:
-	PYTHONPATH=$(PYTHONPATH) python tools/check_bench.py --out bench-check.json
-
-bench-moe:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --only moe_dispatch --json ''
-
-bench-dist:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --only dist --json ''
-
-bench-serve:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --only serve --json ''
-
 test-dist:
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 REPRO_DIST_CHILD=1 \
 		PYTHONPATH=$(PYTHONPATH) python -m pytest -x -q tests/test_dist_plan.py
@@ -76,12 +50,9 @@ test-serve:
 test-train:
 	PYTHONPATH=$(PYTHONPATH) python -m pytest -x -q tests/test_train_engine.py
 
-bench-train:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --only train --json ''
-
 lint:
-	python -m compileall -q src tests benchmarks examples
-	PYTHONPATH=$(PYTHONPATH) python -c "import repro.core.rearrange, repro.core.plan, repro.core.tune, repro.kernels.ops, benchmarks.run, repro.tune"
+	python -m compileall -q src tests examples
+	PYTHONPATH=$(PYTHONPATH) python -c "import repro.core.rearrange, repro.core.plan, repro.core.tune, repro.kernels.ops, repro.tune"
 	@tracked="$$(git ls-files | grep -E '(^|/)__pycache__/|\.pyc$$' || true)"; \
 	if [ -n "$$tracked" ]; then \
 		echo "lint: git-tracked bytecode (commit .gitignore'd files?):"; \

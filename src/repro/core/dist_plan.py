@@ -33,8 +33,8 @@ the wire:
                     wire-optimal path (same contract as the kernels).
 
 Every plan carries the predicted bytes-on-wire of its strategy so callers
-and ``benchmarks/bench_dist.py`` can compare strategies the same way the
-per-device planners expose predicted HBM traffic.
+can compare strategies the same way the per-device planners expose
+predicted HBM traffic.
 
 ``tuned=`` (DESIGN.md §11) ranks every *feasible* strategy decomposition
 through the autotuner's cost model instead of taking the first feasible
@@ -53,9 +53,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import tune
-from repro.core.plan import ICI_GBPS_PER_LINK, plan_rearrange
+from repro.core.plan import plan_rearrange
 from repro.kernels import ops
-from repro.utils.roofline import movement_cost_s
+from repro.utils.roofline import ICI_BW, movement_cost_s
 
 # NOTE: the shard_map/ppermute shims live in repro.launch.mesh and are
 # imported lazily inside the executors — the planner half of this module
@@ -171,7 +171,7 @@ class DistPlan:
             f"{self.bytes_on_wire/1e6:.2f} MB on wire "
             f"(+{self.bytes_local/1e6:.2f} MB local HBM), "
             f"wire roofline {self.wire_roofline_s*1e6:.1f} us "
-            f"@ {ICI_GBPS_PER_LINK} GB/s/link"
+            f"@ {ICI_BW / 1e9:g} GB/s/link"
         )
 
 
@@ -189,7 +189,7 @@ def _mk(workload, strategy, mesh_shape, axis, in_spec, out_spec, local_key,
         collectives=tuple(collectives),
         bytes_on_wire=int(wire),
         bytes_local=int(local),
-        wire_roofline_s=wire / (ICI_GBPS_PER_LINK * 1e9),
+        wire_roofline_s=wire / ICI_BW,
     )
 
 

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import tune
-from repro.core.plan import HBM_GBPS
 from repro.kernels.tiling import (
     VMEM_BUDGET,
     cdiv,
@@ -46,7 +45,7 @@ from repro.kernels.tiling import (
     row_block_candidates,
     sublanes,
 )
-from repro.utils.roofline import movement_cost_s
+from repro.utils.roofline import HBM_BW, movement_cost_s
 
 #: semantics accepted by :func:`plan_index_op`.  ``ragged_rows`` is the
 #: serving engine's pack/unpack route (DESIGN.md §12): a masked gather
@@ -97,7 +96,7 @@ class IndexPlan:
             f"blocks={self.grid}x{self.block_rows} rows"
             f"{f' k={self.top_k}' if self.top_k > 1 else ''} "
             f"{self.bytes_moved/1e6:.2f} MB moved, "
-            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_GBPS} GB/s"
+            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_BW / 1e9:g} GB/s"
         )
 
 
@@ -135,7 +134,7 @@ def _build_plan(
             masked=masked,
             top_k=top_k,
             bytes_moved=bytes_moved,
-            roofline_s=bytes_moved / (HBM_GBPS * 1e9),
+            roofline_s=bytes_moved / HBM_BW,
         )
 
     if n_out == 0 or row_elems == 0:

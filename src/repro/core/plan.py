@@ -48,12 +48,7 @@ from repro.kernels.tiling import (
     vec_tile_candidates,
 )
 from repro.kernels.reorder_nd import affine_tiling_ok
-from repro.utils.roofline import movement_cost_s
-
-# v5e per-chip hardware constants (also used by utils.roofline)
-HBM_GBPS = 819.0
-PEAK_BF16_TFLOPS = 197.0
-ICI_GBPS_PER_LINK = 50.0
+from repro.utils.roofline import HBM_BW, movement_cost_s
 
 
 @dataclass(frozen=True)
@@ -90,7 +85,7 @@ class RearrangePlan:
             f"{self.mode}: shape={self.canonical_shape} perm={self.canonical_perm} "
             f"kernel={self.kernel} {tiles}{ex} source={self.plan_source} tpu={tpu} "
             f"{self.bytes_moved/1e6:.2f} MB moved, "
-            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_GBPS} GB/s"
+            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_BW / 1e9:g} GB/s"
         )
 
 
@@ -204,7 +199,7 @@ def _build_plan(
         block_c=bc,
         grid_order=grid_order,
         bytes_moved=bytes_moved,
-        roofline_s=bytes_moved / (HBM_GBPS * 1e9),
+        roofline_s=bytes_moved / HBM_BW,
         block_v=block_v,
         plan_source=source,
     )
@@ -411,7 +406,7 @@ def _plan_affine_cached(
             canonical_shape=amap.in_digits, canonical_perm=amap.src,
             out_shape=out_shape, exec_shape=None, block_r=1, block_c=1,
             grid_order=grid_order, bytes_moved=bytes_moved,
-            roofline_s=bytes_moved / (HBM_GBPS * 1e9), plan_source="analytic",
+            roofline_s=bytes_moved / HBM_BW, plan_source="analytic",
             tpu_kernel=False,
         )
     m = ex.amap
@@ -430,7 +425,7 @@ def _plan_affine_cached(
         canonical_shape=m.in_digits, canonical_perm=m.src,
         out_shape=out_shape, exec_shape=ex.exec_shape,
         block_r=ex.block_r, block_c=ex.block_c, grid_order=grid_order,
-        bytes_moved=bytes_moved, roofline_s=bytes_moved / (HBM_GBPS * 1e9),
+        bytes_moved=bytes_moved, roofline_s=bytes_moved / HBM_BW,
         block_v=ex.block_v, plan_source="analytic",
         amap=m if ex.mode == "affine" else None,
         tpu_kernel=affine_tiling_ok(ex),

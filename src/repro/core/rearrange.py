@@ -179,5 +179,5 @@ def kv_cache_to_decode_layout(k: Array) -> Array:
 
 
 def plan(x: Array, perm: Sequence[int], *, grid_order: str = "out"):
-    """Expose the (cached) planner for inspection/benchmarks."""
+    """Expose the (cached) planner for inspection."""
     return plan_rearrange(x.shape, x.dtype, tuple(perm), grid_order=grid_order)

@@ -28,11 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import tune
-from repro.core.plan import HBM_GBPS
 from repro.kernels import ops, ref
 from repro.kernels import stencil2d as st_k
 from repro.kernels.tiling import cdiv, neighborhood, round_up, sublanes
-from repro.utils.roofline import movement_cost_s
+from repro.utils.roofline import HBM_BW, movement_cost_s
 
 Array = jax.Array
 
@@ -140,7 +139,7 @@ class StencilPlan:
             + (f"shift={self.shift} " if self.shift else "")
             + f"{self.bytes_moved/1e6:.2f} MB moved vs "
             f"{self.bytes_per_sweep_path/1e6:.2f} MB per-sweep ({saving:.1f}x), "
-            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_GBPS} GB/s"
+            f"roofline {self.roofline_s*1e6:.1f} us @ {HBM_BW / 1e9:g} GB/s"
         )
 
 
@@ -219,7 +218,7 @@ def _build_plan(
         bytes_moved=bytes_fused if mode == "fused" else bytes_per_sweep,
         bytes_per_sweep_path=bytes_per_sweep,
         roofline_s=(bytes_fused if mode == "fused" else bytes_per_sweep)
-        / (HBM_GBPS * 1e9),
+        / HBM_BW,
         shift=shift,
         stages_exec=stages_exec,
     )

@@ -89,20 +89,18 @@ def resolve_mode() -> str:
 
     ``REPRO_TUNE=measure`` / ``REPRO_TUNE=cost`` force a backend; the
     default (``on``) measures only where timing reflects the hardware —
-    a real TPU backend outside interpret mode — and cost-scores
-    everywhere else (CPU containers, ``REPRO_PALLAS_INTERPRET=1``), so CI
-    stays deterministic without configuration.
+    a TPU, where the kernels are compiled — and cost-scores everywhere
+    else (the interpreter off the chip), so CI stays deterministic
+    without configuration.
     """
     v = os.environ.get("REPRO_TUNE", "off").lower()
     if v == "measure":
         return "measure"
     if v == "cost":
         return "cost"
-    from repro.kernels.tiling import force_interpret
+    from repro.kernels.tiling import interpreted
 
-    if jax.default_backend() == "tpu" and not force_interpret():
-        return "measure"
-    return "cost"
+    return "cost" if interpreted() else "measure"
 
 
 # ---------------------------------------------------------------------------

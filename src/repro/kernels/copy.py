@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import LANES, cdiv, force_interpret, plan_copy_tiles
+from repro.kernels.tiling import LANES, cdiv, interpreted, plan_copy_tiles
 
 
 def _copy_kernel(x_ref, o_ref):
@@ -69,7 +69,7 @@ def copy(
     plan = plan_copy_tiles(R, C, x.dtype)
     br = min(block_rows or plan.block_r, R)
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     out = pl.pallas_call(
         _copy_kernel,
         grid=(cdiv(R, br),),
@@ -105,7 +105,7 @@ def copy_range(
     if size % br:
         raise ValueError(f"size {size} not divisible by block_rows {br}")
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(size // br,),

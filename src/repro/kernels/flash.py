@@ -1,4 +1,4 @@
-"""Fused flash attention (Pallas TPU) — hillclimb #1 in EXPERIMENTS §Perf.
+"""Fused flash attention (Pallas TPU).
 
 Why this kernel exists: the pure-JAX chunked attention in
 ``models.attention`` is *algorithmically* flash (online softmax, O(S)
@@ -29,7 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import cdiv, force_interpret, round_up
+from repro.kernels.tiling import cdiv, interpreted, round_up
+from repro.utils.roofline import HBM_BW, movement_cost_s
 
 NEG_INF = -1e30
 
@@ -229,7 +230,7 @@ def flash_attention(
     backward kernels (:func:`flash_attention_bwd`), so no (Sq, Skv)
     attention matrix is ever materialized in either direction.
     """
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return _flash_vjp(q, k, v, causal, q_offset, block_q, block_k, interpret)
 
 
@@ -373,7 +374,7 @@ def flash_attention_triangular(
     backward kernels as :func:`flash_attention` (the backward grid is
     rectangular with causal short-circuit — its upper-triangle tiles cost
     one predicated-off grid step each)."""
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return _flash_tri_vjp(q, k, v, block_q, block_k, interpret)
 
 
@@ -557,7 +558,7 @@ def flash_attention_bwd(
     bq = min(block_q, sq)
     bk = min(block_k, skv)
     nq, nk = cdiv(sq, bq), cdiv(skv, bk)
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
 
     q3 = q.reshape(b * hq, sq, d)
     k3 = k.reshape(b * hkv, skv, d)
@@ -729,7 +730,6 @@ def _bwd_candidates(b, hq, hkv, sq, skv, d, itemsize, causal):
     """The backward search space: the heuristic (block_q, block_k) tile
     first (tie-break contract), then the half/double neighbors."""
     from repro.core import tune
-    from repro.utils.roofline import movement_cost_s
 
     base_bq, base_bk = _bwd_heuristic(sq, skv)
     pairs = [(base_bq, base_bk)]
@@ -770,8 +770,7 @@ def _bwd_runner_factory(b, hq, hkv, sq, skv, d, dtype_name, causal):
         k = tune.sample_array((b, hkv, skv, d), dtype_name)
         v = tune.sample_array((b, hkv, skv, d), dtype_name)
         do = tune.sample_array((b, hq, sq, d), dtype_name)
-        interp = jax.default_backend() != "tpu"
-        o, lse = _flash_call(q, k, v, causal, 0, 512, 512, interp)
+        o, lse = _flash_call(q, k, v, causal, 0, 512, 512, interpreted())
         fn = jax.jit(
             lambda q, k, v, o, lse, do: flash_attention_bwd(
                 q, k, v, o, lse, do, causal=causal,
@@ -791,15 +790,13 @@ def _bwd_mk(b, hq, hkv, sq, skv, d, dtype_name, causal, bq, bk) -> FlashBwdPlan:
     bytes_moved = bwd_dma_bytes(
         b, hq, hkv, sq, skv, d, itemsize, block_q=bq, block_k=bk, causal=causal
     )
-    from repro.core.plan import HBM_GBPS
-
     return FlashBwdPlan(
         block_q=bq,
         block_k=bk,
         grid_dq=(b * hq, nq, nk),
         grid_dkv=(b * hq, nk, nq),
         bytes_moved=bytes_moved,
-        roofline_s=bytes_moved / (HBM_GBPS * 1e9),
+        roofline_s=bytes_moved / HBM_BW,
     )
 
 
@@ -966,7 +963,7 @@ def decode_combine(
     jaxpr-assertable (tests/test_serve_engine.py) and reused verbatim by
     :func:`flash_decode`.  Returns the normalized output (BH, G, d)."""
     bh, ns, g, d = mid_o.shape
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return pl.pallas_call(
         functools.partial(_decode_combine_kernel, num_splits),
         grid=(bh,),
@@ -1033,7 +1030,7 @@ def flash_decode(
         jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (b,)), s_max
     )
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * hkv, ns, nks),
@@ -1122,7 +1119,6 @@ def _decode_candidates(b, hq, hkv, s_max, d, itemsize):
     """The split-KV search space: the heuristic (num_splits, block_k) tile
     first (tie-break contract), then the split-count and block neighbors."""
     from repro.core import tune
-    from repro.utils.roofline import movement_cost_s
 
     base_ns, base_bk = _decode_heuristic(s_max)
     pairs = [(base_ns, base_bk)]
@@ -1203,14 +1199,12 @@ def _decode_mk(b, hq, hkv, s_max, d, dtype_name, ns, bk) -> DecodePlan:
     bytes_moved = decode_dma_bytes(
         b, hq, hkv, s_max, d, itemsize, num_splits=ns, block_k=bk
     )
-    from repro.core.plan import HBM_GBPS
-
     return DecodePlan(
         num_splits=ns,
         block_k=bk,
         grid=(b * hkv, ns, nks),
         bytes_moved=bytes_moved,
-        roofline_s=bytes_moved / (HBM_GBPS * 1e9),
+        roofline_s=bytes_moved / HBM_BW,
     )
 
 

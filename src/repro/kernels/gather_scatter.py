@@ -43,8 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import (
     cdiv,
-    force_interpret,
     hbm_tile_rows,
+    interpreted,
     plan_copy_tiles,
     sublanes,
 )
@@ -80,7 +80,7 @@ def gather_rows(
     bc = min(block_c or plan_copy_tiles(1, C, x.dtype).block_c, C)
     nC = cdiv(C, bc)
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_out, nC),
@@ -117,7 +117,7 @@ def scatter_rows(
     bc = min(block_c or plan_copy_tiles(1, C, x.dtype).block_c, C)
     nC = cdiv(C, bc)
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n, nC),
@@ -290,7 +290,7 @@ def gather_rows_blocked(
     span = br + T
     use_run = br % T == 0 and span <= x.shape[0]
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return pl.pallas_call(
         functools.partial(_gather_block_kernel, use_run),
         grid=(nB,),
@@ -389,7 +389,7 @@ def gather_combine_blocked(
     if n_src % h:  # whole fetch tiles: the only alignment the row fetch needs
         src = jnp.pad(src, ((0, h - n_src % h), (0, 0)))
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return pl.pallas_call(
         _gather_combine_kernel,
         grid=(nT,),

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiling import force_interpret
+from repro.kernels.tiling import interpreted
 
 #: scoped VMEM the kernel may ask for (v5e has 128 MiB per core)
 VMEM_LIMIT = 100 * 1024 * 1024
@@ -94,7 +94,7 @@ def grouped_swiglu(
     if m % tm or tile_group.shape != (m // tm,):
         raise ValueError(f"grouped_swiglu wants {m} rows in whole {tm}-row tiles, "
                          f"one group per tile; got a table of {tile_group.shape}")
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     w_in = pl.BlockSpec((None, d, f), lambda i, tg, nu: (tg[_used_tile(i, nu)], 0, 0))
     rows = pl.BlockSpec((tm, d), lambda i, tg, nu: (_used_tile(i, nu), 0))
     return pl.pallas_call(

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import LANES, force_interpret, sublanes
+from repro.kernels.tiling import LANES, interpreted, sublanes
 
 
 def lane_aligned(length: int) -> bool:
@@ -102,7 +102,7 @@ def interlace(
     dtype = arrays[0].dtype
     rows = L // LANES
     br = _block_rows(rows, n, dtype)
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     out2d = pl.pallas_call(
         functools.partial(_interlace_kernel, n),
         grid=(pl.cdiv(rows, br),),
@@ -130,7 +130,7 @@ def deinterlace(
         raise ValueError(f"L={L} is not a positive multiple of {LANES}")
     rows = L // LANES
     br = _block_rows(rows, n, x.dtype)
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     outs = pl.pallas_call(
         functools.partial(_deinterlace_kernel, n),
         grid=(pl.cdiv(rows, br),),

@@ -2,14 +2,15 @@
 
 Dispatch rules
 --------------
-* On TPU the Pallas kernels own the fast path.
-* On CPU/GPU the jnp oracles (``ref.py``) are the dispatch target — XLA
-  fuses them competitively, and (critically for this container) the
-  multi-pod **dry-run compiles the XLA path**, keeping HLO clean for the
-  roofline analysis.
-* ``REPRO_PALLAS_INTERPRET=1`` forces every op through the Pallas kernel in
-  interpret mode — this is how the test suite validates kernel semantics
-  on CPU.
+* Two predicates decide every dispatch in the library, here and in the
+  model layers: :func:`use_pallas` ("a Pallas kernel runs") and
+  :func:`_interpret` ("it runs in the interpreter").
+* On TPU the Pallas kernels own the fast path, compiled.
+* Elsewhere the jnp oracles (``ref.py``) are the dispatch target — XLA
+  fuses them competitively, and the dry run compiles the XLA path.
+* ``REPRO_PALLAS_INTERPRET=1`` sends every op, prefill and decode
+  attention included, through its Pallas kernel in the interpreter — this
+  is how the test suite validates kernel semantics on CPU.
 * Kernels have alignment preconditions (lane divisibility, the TPU tiling
   rule, a panel that fits VMEM).  Each op checks them through an explicit
   predicate of the plan or the kernel module BEFORE it builds a kernel, and
@@ -40,27 +41,22 @@ from repro.kernels import (
     ref,
     reorder_nd as rnd_k,
     stencil2d as st_k,
+    tiling,
 )
 
 Array = jax.Array
 
 
-def _platform() -> str:
-    return jax.devices()[0].platform
-
-
 def use_pallas() -> bool:
-    """True when dispatch should target the Pallas kernels (TPU, or any
-    platform under ``REPRO_PALLAS_INTERPRET=1``)."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1":
-        return True
-    if os.environ.get("REPRO_DISABLE_PALLAS", "0") == "1":
-        return False
-    return _platform() == "tpu"
+    """True when a Pallas kernel runs: on a TPU, or on any platform under
+    ``REPRO_PALLAS_INTERPRET=1``."""
+    return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1" or not _interpret()
 
 
 def _interpret() -> bool:
-    return _platform() != "tpu"
+    """True when a kernel that runs is interpreted: the platform is not a
+    TPU (:func:`repro.kernels.tiling.interpreted`)."""
+    return tiling.interpreted()
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tiling import (
     cdiv,
-    force_interpret,
+    interpreted,
     plan_transpose_tiles,
     plan_transpose_vec_tiles,
 )
@@ -81,7 +81,7 @@ def transpose2d_batched(
         def out_map(b, i, j):
             return (b, j, i)
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return pl.pallas_call(
         _transpose_kernel,
         grid=(B, nR, nC),
@@ -133,7 +133,7 @@ def transpose2d_batched_vec(
     def out_map(b, i, j, v):
         return (b, j, i, v)
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     return pl.pallas_call(
         _transpose_vec_kernel,
         grid=(B, nR, nC, nV),

@@ -47,7 +47,7 @@ from repro.kernels.tiling import (
     VMEM_BYTES,
     align_block,
     cdiv,
-    force_interpret,
+    interpreted,
     plan_copy_tiles,
     plan_transpose_tiles,
     sublanes,
@@ -277,7 +277,7 @@ def permute_nd(
     pr, pc = _plan_blocks(perm, x.shape, x.dtype)
     br = min(block_r or pr, x.shape[r_in]) if r_in is not None else 1
     bc = min(block_c or pc, x.shape[c_in])
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     base = (0,) * N
     blocks = _tile_blocks(
         perm, x.shape, x.shape, base, x.dtype, br, bc, tpu_rule=not interpret
@@ -465,7 +465,7 @@ def reorder_affine(
         else:
             raise ValueError("lane digit skewed off an undecodable digit")
 
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     block_bytes = math.prod(out_block) * jnp.dtype(x.dtype).itemsize
     out = pl.pallas_call(
         functools.partial(
@@ -518,7 +518,7 @@ def reorder_window(
                 f"window [{base[k]}, {base[k]}+{sizes[k]}) exceeds axis {k} "
                 f"of shape {x.shape}"
             )
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
     base = tuple(int(b) for b in base)
     W = tuple(int(s) for s in sizes)
     blocks = window_blocks(x.shape, x.dtype, perm, base, W, tpu_rule=not interpret)

@@ -65,7 +65,7 @@ from repro.kernels.tiling import (
     LANES,
     VMEM_BYTES,
     cdiv,
-    force_interpret,
+    interpreted,
     round_up,
     sublanes,
 )
@@ -632,7 +632,7 @@ def stencil2d_pipeline(
     geo_boundary = "zero" if (halo_resident and boundary == "periodic") else boundary
     nb = cdiv(H, br)
     roll = shift_route(W, x.dtype, panel, tuple(r for _, r in stages)) == "roll"
-    interpret = force_interpret() if interpret is None else interpret
+    interpret = interpreted() if interpret is None else interpret
 
     def im_cur(i):
         return (i, 0)

@@ -14,9 +14,9 @@ are the C1060 equivalents of exactly these constraints — see DESIGN.md §2.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 # ---------------------------------------------------------------------------
@@ -176,9 +176,11 @@ def align_block(block: int, offset: int) -> int:
     return max(b, 1)
 
 
-def force_interpret() -> bool:
-    """Tests set REPRO_PALLAS_INTERPRET=1 to run kernels on CPU."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
+def interpreted() -> bool:
+    """True when a Pallas kernel runs in the interpreter: the platform is
+    not a TPU.  The one answer to "interpreted or compiled" — every
+    kernel's ``interpret=None`` default and ``ops._interpret`` read it."""
+    return jax.devices()[0].platform != "tpu"
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Multi-pod dry-run: lower + compile every (arch x shape cell) on the
 production meshes; derive the three-term roofline per cell.
 
-Two lowerings per cell (see EXPERIMENTS.md §Dry-run for why):
+Two lowerings per cell:
 
   1. *scan-mode* — the production config exactly as the trainer runs it
      (scan over layers, grad accumulation).  Proves the sharding compiles

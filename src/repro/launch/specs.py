@@ -192,7 +192,7 @@ def resolve_dist(cfg: ModelConfig, mesh, *, serve_decode: bool = False) -> Model
     policy = "head" if cfg.n_heads % ms == 0 else "seq"
     # Megatron-SP measured NEGATIVE on this XLA SPMD backend (collective
     # 7.83->8.32s on qwen2 train_4k: the partitioner keeps the AR and adds
-    # reshards) — opt-in only.  EXPERIMENTS §Perf iteration 6.
+    # reshards) — opt-in only.
     import os
 
     sp = os.environ.get("REPRO_SP", "0") == "1" and not serve_decode

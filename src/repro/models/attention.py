@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import rearrange as rr
+from repro.kernels import flash as flash_k, ops
 from repro.models import common
 from repro.utils.scanutil import maybe_scan
 
@@ -32,33 +33,6 @@ Array = jax.Array
 NEG_INF = -1e30
 #: query / key tile of the fused flash kernel path
 KERNEL_BLOCK = 512
-
-
-def _use_flash_kernel() -> bool:
-    import os
-
-    if os.environ.get("REPRO_FLASH_KERNEL", "") == "1":
-        return True
-    if os.environ.get("REPRO_FLASH_KERNEL", "") == "0":
-        return False
-    return jax.default_backend() == "tpu"
-
-
-def _use_decode_kernel() -> bool:
-    """Split-KV decode dispatch: ``REPRO_DECODE_KERNEL`` (1/0) overrides;
-    default follows the library's Pallas contract (TPU, or any platform
-    under ``REPRO_PALLAS_INTERPRET=1``) so CPU tests keep the jnp oracle
-    unless they opt in."""
-    import os
-
-    v = os.environ.get("REPRO_DECODE_KERNEL", "")
-    if v == "1":
-        return True
-    if v == "0":
-        return False
-    from repro.kernels import ops
-
-    return ops.use_pallas()
 
 
 def _group_q(q: Array, n_kv: int) -> Array:
@@ -81,22 +55,13 @@ def flash_attention(
     prefill continuation / decode with cache).
     """
     b, hkv, skv, d = k.shape
-    import os
-
-    if os.environ.get("REPRO_ATTN_IDENTITY", "0") == "1":
-        # analysis-only: excise attention math so the marginal-unit diff
-        # isolates non-attention traffic; the fused kernel's DMA bytes are
-        # then added from kernels.flash.dma_bytes (EXPERIMENTS §Perf).
-        return q
-    if _use_flash_kernel():
-        # TPU fast path: the fused Pallas kernel (kernels/flash.py) keeps
-        # the logits tile in VMEM — §Perf hillclimb #1.
-        from repro.kernels import flash as flash_k
-
+    if ops.use_pallas():
+        # the fused Pallas kernel (kernels/flash.py) keeps the logits tile
+        # in VMEM
         return flash_k.flash_attention(
             q * (d ** -0.5), k, v, causal=causal, q_offset=q_offset,
             block_q=min(KERNEL_BLOCK, q.shape[2]), block_k=min(KERNEL_BLOCK, skv),
-            interpret=jax.default_backend() != "tpu",
+            interpret=ops._interpret(),
         )
     qg = _group_q(q, hkv)  # (B, Hkv, G, Sq, D)
     sq = qg.shape[3]
@@ -172,7 +137,7 @@ def flash_attention_blockwise(
     cq = min(q_chunk, sq)
     # the KV tile the monolithic call would use: the kernel's key block on
     # the kernel path, the scan chunk otherwise
-    ck = min(KERNEL_BLOCK if _use_flash_kernel() else chunk, skv)
+    ck = min(KERNEL_BLOCK if ops.use_pallas() else chunk, skv)
 
     def block(qc, kc, vc, off):
         return flash_attention(
@@ -250,20 +215,17 @@ def decode_attention(
     ``"splitkv"`` is the two-stage split-KV Pallas kernel
     (`kernels.flash.flash_decode`), ``"oneshot"`` the plain-reduction jnp
     path (GSPMD turns a sequence-sharded cache into partial-softmax +
-    all-reduce automatically); ``None`` resolves from the dispatch contract
-    (`_use_decode_kernel`).
+    all-reduce automatically); ``None`` picks ``"splitkv"`` where a Pallas
+    kernel runs (``ops.use_pallas``).
     """
     b, hkv, s, d = k.shape
     if engine is None:
-        engine = "splitkv" if _use_decode_kernel() else "oneshot"
+        engine = "splitkv" if ops.use_pallas() else "oneshot"
     if engine == "splitkv":
-        from repro.kernels import flash as flash_k
-
         lens = s if length is None else length
         lens = jnp.broadcast_to(jnp.asarray(lens, jnp.int32).reshape(-1), (b,))
         return flash_k.flash_decode(
-            q1, k, v, lengths=lens,
-            interpret=jax.default_backend() != "tpu",
+            q1, k, v, lengths=lens, interpret=ops._interpret(),
         )
     qg = _group_q(q1, hkv)  # (B, Hkv, G, 1, D)
     logits = common.feinsum("bhgqd,bhkd->bhgqk", qg, k) * (d ** -0.5)
@@ -428,7 +390,7 @@ def _shard_qkv(cfg, q: Array, k: Array, v: Array):
             fallback when head counts don't divide the model axis (e.g.
             28 heads on a 16-way axis).  Without this GSPMD contraction-
             shards head_dim and all-reduces the S^2 logits — catastrophic
-            (EXPERIMENTS.md §Perf iteration 1).
+            in the dry run.
     """
     from repro.sharding.partition import BATCH, constrain
     from jax.sharding import PartitionSpec as P

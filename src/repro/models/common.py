@@ -33,7 +33,7 @@ def feinsum(eq: str, a: Array, b: Array) -> Array:
 def bf16_grads(x: Array) -> Array:
     """Identity forward; casts the cotangent to bf16.
 
-    Measured result (EXPERIMENTS §Perf, refuted hypothesis): inserting
+    Measured in the dry run (a refuted hypothesis): inserting
     this after the TP projections does NOT shrink the fp32 all-reduces in
     the qwen2 lowering — those reductions are *forward-side* dot outputs
     that XLA reduces in accumulator precision before the bf16 convert.

@@ -131,8 +131,8 @@ def moe_dense(p: dict, cfg, x: Array, *, capacity: int | None = None) -> tuple[A
     capacity C = cf*S*k/E per batch row, so the dispatch one-hot is
     (B, S, E, C) and dispatch FLOPs stay ~2.5*S^2*D per row (~6% of the
     expert FFN) instead of scaling with GLOBAL tokens — a global capacity
-    makes dispatch O(T^2) (the 7500s collective term the dry-run caught,
-    EXPERIMENTS §Perf).  Expert axis shards on 'model' -> all-to-all."""
+    makes dispatch O(T^2) (the 7500s collective term the dry-run
+    caught).  Expert axis shards on 'model' -> all-to-all."""
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.partition import BATCH, constrain
@@ -202,8 +202,8 @@ def moe_sort(
     the combine is ONE fused gather+weighted-combine kernel, so the whole
     dispatch+combine is exactly 2 `pallas_call`s.  ``engine="rowwise"``
     keeps the seed path — per-row gathers around two full-array sentinel
-    concatenates and an unfused multiply/sum combine — as the benchmark
-    baseline (`benchmarks/bench_moe_dispatch.py`).
+    concatenates and an unfused multiply/sum combine — as a baseline that
+    no benchmark cell runs.
     """
     if engine not in ("plan", "rowwise"):
         raise ValueError(f"unknown moe_sort engine {engine!r}")

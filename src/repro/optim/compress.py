@@ -12,7 +12,7 @@ with error feedback available at the optimizer level.
 Integration: the trainer's DP reduction can route through
 ``compressed_allreduce_mean`` under shard_map when
 ``TrainConfig.compress_grads`` is set; the dry-run's collective-bytes
-accounting then charges int8 operand bytes (see EXPERIMENTS §Perf).
+accounting then charges int8 operand bytes.
 This module is numerically validated on a forced multi-device host mesh
 in tests/test_compress.py.
 """

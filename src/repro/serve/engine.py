@@ -27,9 +27,8 @@ engine pulls them with the logits and records them on the
 
 Static shapes throughout: one compiled ragged prefill per packed width,
 one compiled chunk step, one compiled decode.  The seed's left-padded
-bucket prefill survives as ``prefill_mode="bucket"`` — the measured
-baseline in ``benchmarks/bench_serve.py`` and the only route for
-architectures whose blocks cannot be segment-masked (recurrent state,
+bucket prefill survives as ``prefill_mode="bucket"`` — a baseline no
+benchmark cell runs, and the only route for architectures whose blocks cannot be segment-masked (recurrent state,
 sliding windows: see `models.transformer.supports_ragged`).
 """
 

@@ -68,7 +68,7 @@ def param_spec(path: str, shape: tuple[int, ...], *, cfg, mesh_axes: dict) -> P:
         # dim-sharded gather inside the grad-accumulation while loop
         # ("Slice dim size > dynamic slice dimension" verifier error), so
         # V-sharding it is; the fp32 embed-grad all-reduce this causes is
-        # a known, once-per-step cost (EXPERIMENTS.md §Perf).
+        # a known, once-per-step cost.
         if div(0, msize):
             spec[0] = model
     elif name in _REPLICATED:

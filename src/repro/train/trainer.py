@@ -1,7 +1,7 @@
 """Training step factory: grad-accumulation microbatching, fp32 grad
 accumulators, AdamW update, metrics.
 
-Gradient accumulation is the memory-term lever (EXPERIMENTS.md §Perf):
+Gradient accumulation is the memory-term lever:
 activation temp scales with the microbatch, while the collective term is
 unchanged (grads are reduced once per step, after accumulation).
 """

@@ -1,11 +1,12 @@
-"""Roofline math (TPU v5e constants) — see EXPERIMENTS.md §Roofline.
+"""Roofline math and the one home of the TPU v5e's peaks in the library
+(the planners' ``roofline_s`` and the tuner's cost model read them).
 
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
   memory term     = HLO_bytes_per_device / HBM_bw
   collective term = collective_bytes_per_device / link_bw
 
 cost_analysis() and the HLO text are per-device (post-SPMD) programs, so
-the prompt's global formulation (global / (chips * bw)) reduces to these.
+a global formulation (global / (chips * bw)) reduces to these.
 """
 
 from __future__ import annotations

@@ -260,7 +260,7 @@ def test_flash_kernel_model_path(monkeypatch):
     k = rand((1, 2, 64, 32), jnp.float32)
     v = rand((1, 2, 64, 32), jnp.float32)
     base = attn.flash_attention(q, k, v, causal=True, chunk=32)
-    monkeypatch.setenv("REPRO_FLASH_KERNEL", "1")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     fused = attn.flash_attention(q, k, v, causal=True, chunk=32)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(base), rtol=2e-4, atol=2e-4)
 

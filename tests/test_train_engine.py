@@ -22,7 +22,7 @@ Three families:
   (masked KV chunks pass the online-softmax state through unchanged, so
   truncation is exact); gradients tolerance-match (query-chunking
   reassociates the dk/dv accumulation).  The Pallas kernel dispatch path
-  (`REPRO_FLASH_KERNEL=1`) is exercised explicitly.  The same equivalence
+  (`REPRO_PALLAS_INTERPRET=1`) is exercised explicitly.  The same equivalence
   on the 8-device mesh lives in ``tests/test_dist_plan.py`` (the ``make
   test-dist`` launcher).
 """
@@ -332,7 +332,7 @@ class TestBlockwise:
         """With the Pallas kernel dispatch forced on, the q-chunked wrapper
         (static per-chunk q_offset + aligned KV truncation) bit-matches the
         monolithic kernel call in fwd AND grad."""
-        monkeypatch.setenv("REPRO_FLASH_KERNEL", "1")
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
         q, k, v = qkv(1, 4, 2, 64, 64, 16)
         mono = attention.flash_attention(q, k, v, causal=True, chunk=32)
         bw = attention.flash_attention_blockwise(
@@ -353,7 +353,7 @@ class TestBlockwise:
         """Under nothing_saveable + kernel dispatch, the blockwise grad
         jaxpr lowers to pallas_calls and stages no (B, H, S, S) f32
         matrix."""
-        monkeypatch.setenv("REPRO_FLASH_KERNEL", "1")
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
         b, h, s, d = 1, 4, 64, 16
         q, k, v = qkv(b, h, 2, s, s, d)
 
